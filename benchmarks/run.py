@@ -23,6 +23,9 @@ def main() -> None:
                     help="directory for BENCH_<name>.json artifacts")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_bandwidth, bench_chunked_prefill,
                             bench_end_to_end, bench_fault_tolerance,
                             bench_fused_linear, bench_kv_storage,

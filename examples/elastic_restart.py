@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.distributed.sharding import ShardingPolicy
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ck
 from repro.train.fault_tolerance import ElasticPlan
 from repro.train.trainer import Trainer, TrainerConfig
@@ -29,7 +30,7 @@ def main():
                   ckpt_every=4, ckpt_dir=ckpt)
 
     print("phase 1: train on 4x2 mesh (8 'chips'), checkpoint every 4 steps")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     with mesh:
         tr = Trainer(cfg, TrainerConfig(steps=8, **common),
                      ShardingPolicy(mesh, cfg, mode="train"))
@@ -42,7 +43,7 @@ def main():
     new_mesh_shape = plan.mesh_for(surviving_chips=4)
     print(f"  surviving=4 chips -> mesh {new_mesh_shape}")
 
-    mesh2 = jax.make_mesh(new_mesh_shape, ("data", "model"))
+    mesh2 = make_mesh(new_mesh_shape, ("data", "model"))
     with mesh2:
         tr2 = Trainer(cfg, TrainerConfig(steps=16, **common),
                       ShardingPolicy(mesh2, cfg, mode="train"))

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 
 def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
                    stage_params: Any, x: jnp.ndarray, mesh: Mesh,
@@ -80,7 +78,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     pspec = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
     other = tuple(a for a in mesh.axis_names if a != axis)
     xspec = P(*((None,) * x.ndim))
-    return shard_map(
-        per_stage, mesh, in_specs=(pspec, xspec),
-        out_specs=xspec, check=False,
+    return jax.shard_map(
+        per_stage, mesh=mesh, in_specs=(pspec, xspec),
+        out_specs=xspec, check_vma=False,
     )(stage_params, x)

@@ -111,6 +111,13 @@ class ShardingPolicy:
         table = {
             # [B, T, D]
             "activation": P(dp, None, None),
+            # [B, T, K] input of a projection that contracts over K (the
+            # attention output before wo, the MLP hidden before down).
+            # Serve mode pins it whole: left free, the partitioner may
+            # shard K over an idle mesh axis (a batch-1 prefill on
+            # data > 1) and all-reduce partial sums.  Training leaves it
+            # to the partitioner (Megatron row-parallel wo/down).
+            "contracted": None if train else P(dp, None, None),
             # [B, T, D] inter-stage residual carry: sequence-parallel in
             # training (the per-stage saved residuals dominate HBM
             # otherwise — Megatron-SP); replicated-T at inference.
@@ -206,10 +213,11 @@ class ShardingPolicy:
             # training: Megatron row-parallel (input dim over model — one
             # psum per block).  serve: column split over the *output* dim —
             # the head-sharded attention output is all-gathered instead,
-            # so no cross-device reduction ever reorders fp sums and the
-            # sharded engine stays bit-identical to the unsharded one (the
-            # serving identity contract tests/test_sharded_serve.py pins;
-            # all-gathers move the same bytes as the psum at decode M).
+            # so no cross-device reduction reorders fp sums (all-gathers
+            # move the same bytes as the psum at decode M).  A shard's
+            # narrower matmul may still round differently from one
+            # device's: sharded serving matches it to rounding
+            # (docs/distributed.md).
             return (P("model", fsdp) if self.mode == "train"
                     else P(fsdp, "model"))
         # --- MLP ---
@@ -217,7 +225,7 @@ class ShardingPolicy:
             return P(fsdp, "model")
         if path.endswith("down/w"):
             # row-parallel in training, column split at serve time — same
-            # bit-identity rationale as wo/w above.
+            # rationale as wo/w above.
             return (P("model", fsdp) if self.mode == "train"
                     else P(fsdp, "model"))
         # --- SSM ---
@@ -228,7 +236,7 @@ class ShardingPolicy:
         if path.endswith("out_proj/w"):
             # same train-row / serve-column split as wo/w and down/w: a
             # Mamba block's output projection must not psum at serve time
-            # either, or hybrid-arch sharded serving loses bit-identity.
+            # either.
             return (P("model", fsdp) if self.mode == "train"
                     else P(fsdp, "model"))
         if path.endswith("conv_x_w"):

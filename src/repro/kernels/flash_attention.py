@@ -46,8 +46,8 @@ def _flash_kernel(qpos_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
                             preferred_element_type=jnp.float32)  # [bq, bk]
 
     kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    q_pos = qpos_ref[0][:, None]                          # [bq, 1]
-    mask = kv_pos < kvlen_ref[0, 0]
+    q_pos = qpos_ref[0]                                   # [bq, 1]
+    mask = kv_pos < kvlen_ref[0]                          # [1, 1] bcast
     if causal:
         mask &= kv_pos <= q_pos
     if window:
@@ -94,6 +94,10 @@ def flash_attention_packed(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.pad(k, ((0, 0), (0, Tp - Tk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, Tp - Tk), (0, 0)))
 
+    # per-row vectors ride as [.., R, 1] / [.., 1, 1] columns so every
+    # block's last two dims are whole array dims (TPU (8, 128) tiling)
+    q_pos = q_pos[..., None]
+    kv_len = kv_len.reshape(BH, 1, 1)
     grid = (BH, Rp // bq, Tp // bk)
     kernel = functools.partial(_flash_kernel, bk=bk, causal=causal,
                                window=window, scale=scale)
@@ -101,8 +105,8 @@ def flash_attention_packed(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),          # q_pos
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),           # kv_len
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),    # q_pos
+            pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0)),     # kv_len
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),   # q
             pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),   # k
             pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),   # v

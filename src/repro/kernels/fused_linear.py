@@ -13,8 +13,9 @@ op of the routed block runs as a single VMEM-resident pipeline:
     FP→BFP row-quantization directly, then int8×int4 products accumulate
     in int32 with one FP reconstruction per (row, K-group).
   * **epilogue** — optional SwiGLU/GeGLU gating over a widened
-    ``[gate | up]`` output (stored as ``[K, 2, F]`` so one weight tile
-    carries both halves of an output block), optional per-row gate
+    ``[gate | up]`` output (the ``[K, 2F]`` weight is fed twice, as the
+    gate tile at column block j and the up tile at j + F/bn, so one grid
+    cell holds both halves of an output block), optional per-row gate
     multiplier, optional residual add, and optional incremental emission
     of Σy² of the written residual stream — the *next* block's norm
     reduction (the paper's incremental-reduction carry) comes out of this
@@ -34,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.int4_matmul import MBITS, _bfp_quantize_rows
+from repro.kernels.int4_matmul import MBITS, _bfp_quantize_rows, int8_dot
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
@@ -60,8 +61,10 @@ def _fused_linear_kernel(*refs, prologue: bool, int4: bool, glu: bool,
     x_ref = next(it)
     ms_ref = next(it) if prologue else None
     g_ref = next(it) if prologue else None
-    w_ref = next(it)
-    s_ref = next(it) if int4 else None
+    # one (weight, scale) pair per output half: [gate, up] under glu
+    halves = 2 if glu else 1
+    w_refs = [next(it) for _ in range(halves)]
+    s_refs = [next(it) for _ in range(halves)] if int4 else None
     res_ref = next(it) if has_res else None
     gm_ref = next(it) if has_gmul else None
     o_ref = next(it)
@@ -73,6 +76,7 @@ def _fused_linear_kernel(*refs, prologue: bool, int4: bool, glu: bool,
     k = pl.program_id(2)
     nj = pl.num_programs(1)
     nk = pl.num_programs(2)
+    bn = o_ref.shape[-1]
 
     @pl.when(k == 0)
     def _init():
@@ -90,33 +94,25 @@ def _fused_linear_kernel(*refs, prologue: bool, int4: bool, glu: bool,
         x = x * jax.lax.rsqrt(ms_ref[...] + eps) \
               * g_ref[...].astype(jnp.float32)
 
+    def halves_cat(parts):                                  # [.., halves·bn]
+        return parts[0] if halves == 1 else jnp.concatenate(parts, axis=-1)
+
+    w = halves_cat([r[...] for r in w_refs])                # [bk, halves·bn]
     if int4:
         mant, pe = _bfp_quantize_rows(x)                    # BFP domain
-        w = w_ref[...]                                      # int8 codes
-        if glu:
-            w = w.reshape(w.shape[0], -1)                   # [bk, 2·bn]
-        prod = jax.lax.dot_general(
-            mant.astype(jnp.int32), w.astype(jnp.int32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)               # fixed point
-        s = s_ref[...]
-        if glu:
-            s = s.reshape(1, -1)
+        prod = int8_dot(mant, w)                            # fixed point
+        s = halves_cat([r[pl.ds(k, 1), :] for r in s_refs])  # [1, ·]
         acc_scr[...] += (prod.astype(jnp.float32)
                          * (pe * (2.0 ** -MBITS)) * s)
     else:
-        w = w_ref[...].astype(jnp.float32)
-        if glu:
-            w = w.reshape(w.shape[0], -1)                   # [bk, 2·bn]
         acc_scr[...] += jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())),
+            x, w.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _fin():
         acc = acc_scr[...]
         if glu:
-            bn = acc.shape[-1] // 2
             y = _act(acc[:, :bn], act) * acc[:, bn:]
         else:
             y = _act(acc, act)
@@ -181,14 +177,10 @@ def fused_linear_pallas(x: jnp.ndarray, w: Optional[jnp.ndarray] = None,
     Fp = -(-F // bn) * bn
     Kp = -(-Kw // bk) * bk
 
-    if glu:                                                 # [K, 2, F]
-        wt = wt.reshape(Kw, 2, F)
-        if int4:
-            scale = scale.reshape(scale.shape[0], 2, F)
     if Kp != Kw or Kp != K:
         x = jnp.pad(x, ((0, 0), (0, Kp - K)))
         if Kp != Kw:
-            wt = jnp.pad(wt, ((0, Kp - Kw),) + ((0, 0),) * (wt.ndim - 1))
+            wt = jnp.pad(wt, ((0, Kp - Kw), (0, 0)))
         if prologue:
             gamma = jnp.pad(gamma, (0, Kp - K))
     if Mp != M:
@@ -200,15 +192,24 @@ def fused_linear_pallas(x: jnp.ndarray, w: Optional[jnp.ndarray] = None,
         if gate_mul is not None:
             gate_mul = jnp.pad(gate_mul, (0, Mp - M))
     if Fp != F:
-        pads = ((0, 0),) * (wt.ndim - 1) + ((0, Fp - F),)
-        wt = jnp.pad(wt, pads)
+        # pad each output half ([gate | up] under glu) to a bn multiple
+        halves = 2 if glu else 1
+
+        def pad_cols(a):
+            r = a.shape[0]
+            return jnp.pad(a.reshape(r, halves, F),
+                           ((0, 0), (0, 0), (0, Fp - F))
+                           ).reshape(r, halves * Fp)
+
+        wt = pad_cols(wt)
         if int4:
-            scale = jnp.pad(scale, pads)
+            scale = pad_cols(scale)
         if residual is not None:
             residual = jnp.pad(residual, ((0, 0), (0, Fp - F)))
 
     grid = (Mp // bm, Fp // bn, Kp // bk)
     wb = 2 * bn if glu else bn
+    nf = Fp // bn                   # column blocks per half
 
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))]
     inputs = [x]
@@ -216,18 +217,18 @@ def fused_linear_pallas(x: jnp.ndarray, w: Optional[jnp.ndarray] = None,
         in_specs += [pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
                      pl.BlockSpec((1, bk), lambda i, j, k: (0, k))]
         inputs += [mean_sq.astype(jnp.float32)[:, None], gamma[None, :]]
-    if glu:
-        in_specs.append(pl.BlockSpec((bk, 2, bn), lambda i, j, k: (k, 0, j)))
-    else:
-        in_specs.append(pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)))
-    inputs.append(wt)
+    half_offsets = (0, nf) if glu else (0,)
+    for off in half_offsets:
+        in_specs.append(pl.BlockSpec(
+            (bk, bn), lambda i, j, k, off=off: (k, j + off)))
+        inputs.append(wt)
     if int4:
-        if glu:
-            in_specs.append(
-                pl.BlockSpec((1, 2, bn), lambda i, j, k: (k, 0, j)))
-        else:
-            in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (k, j)))
-        inputs.append(scale)
+        # the whole [K/G, bn] scale column stays resident; the kernel
+        # reads row k (a (1, bn) block would break the (8, 128) tiling)
+        for off in half_offsets:
+            in_specs.append(pl.BlockSpec(
+                (scale.shape[0], bn), lambda i, j, k, off=off: (0, j + off)))
+            inputs.append(scale)
     if residual is not None:
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)))
         inputs.append(residual)

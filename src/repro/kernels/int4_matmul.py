@@ -38,6 +38,14 @@ def _bfp_quantize_rows(x: jnp.ndarray):
     return mant.astype(jnp.int8), pe
 
 
+def int8_dot(mant: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
+    """[bm, G] int8 mantissas × [G, bn] int8 codes -> [bm, bn] int32:
+    the MXU's native int8 path, accumulated in fixed point."""
+    return jax.lax.dot_general(mant, codes.astype(jnp.int8),
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
 def _int4_kernel(x_ref, w_ref, s_ref, o_ref, acc_scr, *, out_dtype):
     k = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -48,12 +56,8 @@ def _int4_kernel(x_ref, w_ref, s_ref, o_ref, acc_scr, *, out_dtype):
 
     x = x_ref[...].astype(jnp.float32)                    # [bm, G]
     mant, pe = _bfp_quantize_rows(x)
-    w = w_ref[...]                                        # [G, bn] int8 codes
-    prod = jax.lax.dot_general(
-        mant.astype(jnp.int32), w.astype(jnp.int32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)                 # fixed-point acc
-    scale = s_ref[...]                                    # [1, bn]
+    prod = int8_dot(mant, w_ref[...])                     # [G, bn] codes
+    scale = s_ref[pl.ds(k, 1), :]                         # [1, bn]
     acc_scr[...] += (prod.astype(jnp.float32)
                      * (pe * (2.0 ** -MBITS))             # [bm, 1]
                      * scale)                             # [1, bn]
@@ -91,7 +95,10 @@ def int4_matmul_pallas(x: jnp.ndarray, w_codes: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, G), lambda i, j, k: (i, k)),
             pl.BlockSpec((G, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
+            # the whole [K/G, bn] scale column stays resident; the kernel
+            # reads its group's row (a (1, bn) block would break the
+            # TPU's (8, 128) tiling)
+            pl.BlockSpec((K // G, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),

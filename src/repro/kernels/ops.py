@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles layout packing (GQA head packing), padding, backend dispatch
-(interpret=True off-TPU so CPU tests execute the kernel bodies), and the
-pure-jnp fallbacks used by the dry-run lowering.
+(interpret=True on the CPU backend only, so CPU tests execute the kernel
+bodies; on any other backend a kernel compiles for real or raises), and
+the pure-jnp fallbacks used by the dry-run lowering.
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ from repro.kernels.paged_attention import paged_attention_packed
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------

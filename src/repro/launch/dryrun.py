@@ -30,7 +30,8 @@ from repro.configs import ASSIGNED_ARCHS, SHAPES, get_config
 from repro.distributed.sharding import ShardingPolicy
 from repro.launch import specs as specs_lib
 from repro.launch.mesh import make_production_mesh
-from repro.roofline.analysis import analyze_compiled, model_flops_for
+from repro.roofline.analysis import (DRYRUN_DEVICE_KIND, analyze_compiled,
+                                     model_flops_for)
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
 
@@ -92,7 +93,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     chips = mesh.devices.size
     analysis = analyze_compiled(compiled, chips=chips,
                                 model_flops=model_flops_for(cfg, kind, tokens),
-                                shape_kind=kind)
+                                shape_kind=kind,
+                                device_kind=DRYRUN_DEVICE_KIND)
     rec.update(status="ok", lower_s=round(t_lower, 1),
                compile_s=round(t_compile, 1), kind=kind,
                tokens=tokens, **analysis)

@@ -1,14 +1,33 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION (not a module constant) so importing this module never touches
+FUNCTIONS (not module constants) so importing this module never touches
 jax device state.  The dry-run sets XLA_FLAGS for 512 placeholder host
 devices *before* any jax import (see dryrun.py).
+
+Every mesh has ``AxisType.Auto`` axes: the model code places arrays with
+sharding hints and lets the partitioner propagate them (``jax.make_mesh``
+defaults to ``Explicit`` axes, under which e.g. the vocab-sharded
+embedding gather must name its output sharding).
 """
 from __future__ import annotations
 
-import jax
+from typing import Sequence, Tuple
 
-from repro.distributed.compat import make_mesh
+import jax
+from jax.sharding import AbstractMesh, AxisType
+
+
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str]):
+    """A device mesh over the visible devices with Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
+def abstract_mesh(axes: Sequence[Tuple[str, int]]) -> AbstractMesh:
+    """Device-free mesh from ((axis_name, size), ...) pairs, Auto axes."""
+    names = tuple(n for n, _ in axes)
+    sizes = tuple(s for _, s in axes)
+    return AbstractMesh(sizes, names, (AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

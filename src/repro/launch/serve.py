@@ -3,6 +3,9 @@ pipeline (gather-mode routing + cross-layer KV reuse).
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
       --batch 4 --prompt-len 64 --new-tokens 32
+
+``build_parser`` / ``build_model`` / ``build_engine`` are the launcher's
+construction path; ``chip_smoke.py`` drives the same functions.
 """
 import argparse
 import dataclasses
@@ -11,10 +14,13 @@ import jax
 import numpy as np
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights (there are no weight "
+                         "files)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=32)
@@ -30,6 +36,9 @@ def main() -> None:
                     help="paged KV store + history buffer instead of the "
                          "dense slot pool (see docs/kvcache.md)")
     ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--num-pages", type=int, default=None,
+                    help="paged-KV page budget (default: every slot's "
+                         "worst case; requires --paged-kv)")
     ap.add_argument("--kv-dtype", default=None,
                     choices=("int8", "int4"),
                     help="quantize paged-KV page payloads (per-entry "
@@ -120,31 +129,11 @@ def main() -> None:
                     help="write per-request results (tokens, finish "
                          "reason) as JSON — the kill/resume smoke "
                          "compares these across runs")
-    args = ap.parse_args()
+    return ap
 
-    from repro.configs import get_config
-    from repro.models import model as model_lib
-    from repro.serve.config import (EngineConfig, KVConfig, ObsConfig,
-                                    RobustnessConfig, SchedulingConfig,
-                                    SpecConfig)
-    from repro.serve.engine import ContinuousBatchingEngine, ServeEngine
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.smoke()
-    if args.use_kernels:
-        cfg = dataclasses.replace(cfg, use_kernels=True)
-    if args.gather:
-        cfg = dataclasses.replace(
-            cfg, skip=dataclasses.replace(cfg.skip, mode="gather"))
-    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
-    if args.int4:
-        from repro.quant import quantize_params
-        params = quantize_params(params, cfg.quant.group_size,
-                                 cfg.quant.pow2_scales)
-
-    rng = np.random.default_rng(0)
-    max_len = args.prompt_len + args.new_tokens
+def check_args(args: argparse.Namespace) -> None:
+    """Reject flag combinations the engine cannot serve (SystemExit)."""
     if args.prefill_chunk and not args.continuous:
         raise SystemExit("--prefill-chunk requires --continuous")
     if args.decode_steps and not args.continuous:
@@ -154,8 +143,10 @@ def main() -> None:
     if args.spec_k and args.decode_steps:
         raise SystemExit("--spec-k and --decode-steps are mutually "
                          "exclusive (both own the decode cadence)")
-    if (args.kv_dtype or args.prefix_cache) and not args.paged_kv:
-        raise SystemExit("--kv-dtype/--prefix-cache require --paged-kv")
+    if ((args.kv_dtype or args.prefix_cache or args.num_pages)
+            and not args.paged_kv):
+        raise SystemExit("--kv-dtype/--prefix-cache/--num-pages require "
+                         "--paged-kv")
     if args.draft_keep is not None and not args.spec_k:
         raise SystemExit("--draft-keep requires --spec-k")
     if args.tp and not args.continuous:
@@ -169,6 +160,83 @@ def main() -> None:
     if any(v is not None for v in robust) and not args.continuous:
         raise SystemExit("robustness flags (--deadline-s/--snapshot-dir/"
                          "--resume/--kill-at/...) require --continuous")
+
+
+def model_config(args: argparse.Namespace):
+    """The arch's ModelConfig with the launcher's levers applied."""
+    from repro.configs import get_config
+
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = cfg.smoke()
+    if args.use_kernels:
+        cfg = dataclasses.replace(cfg, use_kernels=True)
+    if args.gather:
+        cfg = dataclasses.replace(
+            cfg, skip=dataclasses.replace(cfg.skip, mode="gather"))
+    return cfg
+
+
+def build_model(args: argparse.Namespace):
+    """(cfg, params): ``model_config`` and seeded random weights (there
+    are no weight files), int4-coded layer by layer with --int4."""
+    from repro.models import model as model_lib
+
+    cfg = model_config(args)
+    params = model_lib.init_params(jax.random.PRNGKey(args.seed), cfg,
+                                   quantize=args.int4)
+    return cfg, params
+
+
+def build_engine(args: argparse.Namespace, cfg, params, mesh=None):
+    """The continuous-batching engine the flags describe (``mesh``: the
+    ``--tp`` serving mesh, or None for one device)."""
+    from repro.serve.config import (EngineConfig, KVConfig, ObsConfig,
+                                    RobustnessConfig, SchedulingConfig,
+                                    SpecConfig)
+    from repro.serve.engine import ContinuousBatchingEngine
+    from repro.serve.faults import Fault, Watchdog
+
+    faults = ([Fault("kill", step=args.kill_at)]
+              if args.kill_at is not None else None)
+    watchdog = (Watchdog(timeout_s=args.watchdog_timeout_s)
+                if args.watchdog_timeout_s is not None else None)
+    return ContinuousBatchingEngine(cfg, params, config=EngineConfig(
+        kv=KVConfig(
+            kv_mode="paged" if args.paged_kv else "dense",
+            page_size=args.page_size,
+            num_pages=args.num_pages,
+            kv_dtype=args.kv_dtype,
+            prefix_cache=args.prefix_cache,
+            prefix_block=args.prefix_block),
+        scheduling=SchedulingConfig(
+            max_slots=args.batch,
+            max_len=args.prompt_len + args.new_tokens,
+            prefill_chunk=args.prefill_chunk,
+            decode_steps=args.decode_steps or None),
+        spec=SpecConfig(spec_k=args.spec_k, draft_keep=args.draft_keep),
+        robustness=RobustnessConfig(
+            faults=faults, watchdog=watchdog,
+            snapshot_dir=args.snapshot_dir,
+            snapshot_every=args.snapshot_every,
+            max_queue_depth=args.max_queue_depth,
+            max_queue_delay_s=args.max_queue_delay_s,
+            max_preemptions=args.max_preemptions),
+        obs=ObsConfig(trace=args.trace_out, mesh=mesh),
+        temperature=args.temperature))
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    check_args(args)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve.engine import ServeEngine
+
+    enable_compile_cache()
+    cfg, params = build_model(args)
+    rng = np.random.default_rng(args.seed)
+    max_len = args.prompt_len + args.new_tokens
     mesh = None
     if args.tp:
         from repro.launch.mesh import make_serve_mesh
@@ -176,33 +244,7 @@ def main() -> None:
         print(f"tensor-parallel serving: mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}")
     if args.continuous:
         from repro.serve.errors import SimulatedKill
-        from repro.serve.faults import Fault, Watchdog
-        faults = ([Fault("kill", step=args.kill_at)]
-                  if args.kill_at is not None else None)
-        watchdog = (Watchdog(timeout_s=args.watchdog_timeout_s)
-                    if args.watchdog_timeout_s is not None else None)
-        eng = ContinuousBatchingEngine(cfg, params, config=EngineConfig(
-            kv=KVConfig(
-                kv_mode="paged" if args.paged_kv else "dense",
-                page_size=args.page_size,
-                kv_dtype=args.kv_dtype,
-                prefix_cache=args.prefix_cache,
-                prefix_block=args.prefix_block),
-            scheduling=SchedulingConfig(
-                max_slots=args.batch, max_len=max_len,
-                prefill_chunk=args.prefill_chunk,
-                decode_steps=args.decode_steps or None),
-            spec=SpecConfig(spec_k=args.spec_k,
-                            draft_keep=args.draft_keep),
-            robustness=RobustnessConfig(
-                faults=faults, watchdog=watchdog,
-                snapshot_dir=args.snapshot_dir,
-                snapshot_every=args.snapshot_every,
-                max_queue_depth=args.max_queue_depth,
-                max_queue_delay_s=args.max_queue_delay_s,
-                max_preemptions=args.max_preemptions),
-            obs=ObsConfig(trace=args.trace_out, mesh=mesh),
-            temperature=args.temperature))
+        eng = build_engine(args, cfg, params, mesh)
         if args.resume:
             at = eng.resume()
             print(f"resumed from snapshot boundary {at} "

@@ -181,15 +181,9 @@ def _param_shapes(cfg: ModelConfig):
     """Parameter ShapeDtypeStructs — int4-coded when cfg.quant.enabled
     (the paper's W4 deployment: the dry-run lowers against the quantized
     tree so weight HBM/collective bytes reflect int4 storage)."""
-    def init(key):
-        p = model_lib.init_params(key, cfg)
-        if cfg.quant.enabled:
-            from repro.quant import quantize_params
-            p = quantize_params(p, cfg.quant.group_size,
-                                cfg.quant.pow2_scales)
-        return p
-
-    return jax.eval_shape(init, jax.random.PRNGKey(0))
+    return jax.eval_shape(
+        partial(model_lib.init_params, cfg=cfg, quantize=cfg.quant.enabled),
+        jax.random.PRNGKey(0))
 
 
 def build_prefill_step(cfg: ModelConfig, policy: ShardingPolicy,

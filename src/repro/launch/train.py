@@ -42,8 +42,12 @@ def main() -> None:
                     help="data,model e.g. 2,2 (needs that many devices)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import get_config
     from repro.distributed.sharding import ShardingPolicy
+    from repro.launch.mesh import make_mesh
     from repro.train.trainer import Trainer, TrainerConfig
 
     cfg = get_config(args.arch)
@@ -52,7 +56,7 @@ def main() -> None:
     policy = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         policy = ShardingPolicy(mesh, cfg, mode="train")
 
     tcfg = TrainerConfig(seq_len=args.seq, global_batch=args.batch,

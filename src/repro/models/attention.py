@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import hint
 from repro.models import layers
 from repro.models.layers import Params
 
@@ -149,7 +150,8 @@ def project_qkv(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
 
 def output_proj(params: Params, o: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     B, T = o.shape[:2]
-    return layers.linear_apply(params["wo"], o.reshape(B, T, cfg.attn_inner_dim), cfg)
+    o = hint(o.reshape(B, T, cfg.attn_inner_dim), "contracted")
+    return layers.linear_apply(params["wo"], o, cfg)
 
 
 def output_proj_fused(params: Params, o: jnp.ndarray, cfg: ModelConfig, *,

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import hint
 
 Params = Dict[str, jnp.ndarray]
 
@@ -56,9 +57,9 @@ def slice_linear(params: Params, lo: int, hi: int) -> Params:
     the legacy split views over merged wqkv / w_gu weights.  Per-group
     scales index output columns, so slicing preserves the BFP grouping."""
     if "w_int" in params:
-        return {"w_int": params["w_int"][:, lo:hi],
-                "scale": params["scale"][:, lo:hi]}
-    return {"w": params["w"][:, lo:hi]}
+        return {"w_int": params["w_int"][..., lo:hi],
+                "scale": params["scale"][..., lo:hi]}
+    return {"w": params["w"][..., lo:hi]}
 
 
 def fuse_norm_linear(cfg: ModelConfig) -> bool:
@@ -245,7 +246,7 @@ def mlp_apply(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
             h = jax.nn.gelu(linear_apply(params["gate"], x, cfg)) * up
         else:
             h = jax.nn.gelu(up)
-    return linear_apply(params["down"], h, cfg)
+    return linear_apply(params["down"], hint(h, "contracted"), cfg)
 
 
 def mlp_apply_fused(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
@@ -280,20 +281,21 @@ def _concat_linears(parts) -> Params:
     """Column-concat linear param dicts.  All-quantized parts concat in
     the code domain; a mixed dense/int4 list (quantize_params' size
     threshold can split a legacy wq/wk/wv trio) is dequantized to a dense
-    merge — correctness over storage for that corner."""
+    merge — correctness over storage for that corner.  Leaves may carry
+    a leading scan-stacked stage axis: everything indexes from the end."""
     if all("w_int" in p for p in parts) and len(
-            {p["w_int"].shape[0] for p in parts}) == 1:
-        return {"w_int": jnp.concatenate([p["w_int"] for p in parts], 1),
-                "scale": jnp.concatenate([p["scale"] for p in parts], 1)}
+            {p["w_int"].shape[-2] for p in parts}) == 1:
+        return {"w_int": jnp.concatenate([p["w_int"] for p in parts], -1),
+                "scale": jnp.concatenate([p["scale"] for p in parts], -1)}
     from repro.quant import dequantize
 
     dense = [p for p in parts if "w" in p]
-    k = dense[0]["w"].shape[0] if dense else parts[0]["w_int"].shape[0]
+    k = dense[0]["w"].shape[-2] if dense else parts[0]["w_int"].shape[-2]
     dt = dense[0]["w"].dtype if dense else jnp.float32
     ws = [p["w"] if "w" in p
           else dequantize(p["w_int"], p["scale"], k=k).astype(dt)
           for p in parts]
-    return {"w": jnp.concatenate(ws, axis=1)}
+    return {"w": jnp.concatenate(ws, axis=-1)}
 
 
 def merge_legacy_linear_params(params: Params) -> Params:

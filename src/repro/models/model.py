@@ -11,6 +11,7 @@ Public entry points (all pure functions of (cfg, params, ...)):
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -26,16 +27,54 @@ from repro.models.layers import Params
 # Init
 # ---------------------------------------------------------------------------
 
-def init_params(key, cfg: ModelConfig) -> Params:
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_stage(stack: Params, i, stage: Params) -> Params:
+    """``stack[i] = stage`` leaf by leaf, in place (the stack is donated)."""
+    return jax.tree_util.tree_map(
+        lambda b, x: jax.lax.dynamic_update_index_in_dim(b, x, i, 0),
+        stack, stage)
+
+
+def init_params(key, cfg: ModelConfig, quantize: bool = False) -> Params:
+    """Seeded parameter pytree; with ``quantize`` every large linear weight
+    is int4-coded (``quant.quantize_params`` with ``cfg.quant``'s group
+    size and scale rule).
+
+    Built one stage at a time: each stage is drawn (fp32 draw, then cast)
+    and quantized on its own and written into the preallocated
+    ``[S-1, ...]`` stack by a jitted update that donates the stack, so
+    the live peak is the final tree plus one stage's temporaries — never
+    an fp32 or pre-quantization copy of the whole stack (at llama2-7b's
+    widths that copy alone is 11 GB).  The values equal
+    ``transformer.stack_init``'s ``vmap(stage_init)`` over the same
+    per-stage keys."""
+    from repro.quant import quantize_params
+
+    def post(p):
+        if not quantize:
+            return p
+        return quantize_params(p, cfg.quant.group_size,
+                               cfg.quant.pow2_scales)
+
     ks = jax.random.split(key, 4)
+    sk = jax.random.split(ks[1], cfg.num_stages)
+    stack: Params = {"stage0": post(transformer.stage_init(sk[0], cfg))}
+    for i in range(1, cfg.num_stages):
+        stage = post(transformer.stage_init(sk[i], cfg))
+        if i == 1:
+            stack["stages"] = jax.tree_util.tree_map(
+                lambda x: jnp.zeros((cfg.num_stages - 1,) + x.shape,
+                                    x.dtype), stage)
+        stack["stages"] = _set_stage(stack["stages"], i - 1, stage)
     p: Params = {
         "embed": layers.embedding_init(ks[0], cfg),
-        "stack": transformer.stack_init(ks[1], cfg),
+        "stack": stack,
         "final_norm": layers.norm_init(cfg.d_model, cfg),
     }
     if not cfg.tie_embeddings:
-        p["lm_head"] = layers.linear_init(ks[2], cfg.d_model, cfg.vocab_size,
-                                          cfg, scale=0.02)
+        p["lm_head"] = post(layers.linear_init(ks[2], cfg.d_model,
+                                               cfg.vocab_size, cfg,
+                                               scale=0.02))
     return p
 
 
@@ -243,15 +282,10 @@ def _pad_cache_to(cache: Dict, T: int, pad_to: int, cfg: ModelConfig) -> Dict:
     return jax.tree_util.tree_map_with_path(one, cache)
 
 
-def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
-            pad_to: Optional[int] = None,
-            last_index: Optional[jnp.ndarray] = None
-            ) -> Tuple[jnp.ndarray, Dict, Dict]:
-    """Returns (last-position logits [B, V], cache, stats).
-
-    ``last_index``: optional [B] int32 index of each sequence's final *real*
-    token — bucketed prefill right-pads prompts to a shared length, and the
-    next-token logits must come from the real last position, not the pad."""
+def _prefill_hidden(params: Params, batch: Dict[str, jnp.ndarray],
+                    cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict, Dict]:
+    """Final-normed hidden states [B, T, D] of a full forward, with the
+    per-layer caches and stats."""
     if cfg.frontend == "token":
         B, T = batch["tokens"].shape
     else:
@@ -264,6 +298,29 @@ def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
         x, stats, cache, sq = _apply_stack(params, x, positions, cfg, None,
                                            False, True)
     x = layers.norm_apply(params["final_norm"], x, cfg, stats=sq)
+    return x, cache, stats
+
+
+def sequence_logits(params: Params, batch: Dict[str, jnp.ndarray],
+                    cfg: ModelConfig) -> jnp.ndarray:
+    """Next-token logits [B, T, V] at every position of a full forward
+    (teacher forcing): what checks a decode path's greedy tokens against
+    the model, position by position."""
+    x, _, _ = _prefill_hidden(params, batch, cfg)
+    return layers.unembed(params["embed"], params.get("lm_head"), x, cfg)
+
+
+def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
+            pad_to: Optional[int] = None,
+            last_index: Optional[jnp.ndarray] = None
+            ) -> Tuple[jnp.ndarray, Dict, Dict]:
+    """Returns (last-position logits [B, V], cache, stats).
+
+    ``last_index``: optional [B] int32 index of each sequence's final *real*
+    token — bucketed prefill right-pads prompts to a shared length, and the
+    next-token logits must come from the real last position, not the pad."""
+    x, cache, stats = _prefill_hidden(params, batch, cfg)
+    B, T = x.shape[:2]
     if last_index is None:
         xl = x[:, -1:, :]
     else:

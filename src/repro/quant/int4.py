@@ -9,6 +9,7 @@ accumulation tree.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -17,6 +18,9 @@ import jax.numpy as jnp
 Params = Dict[str, jnp.ndarray]
 
 INT4_MIN, INT4_MAX = -8, 7
+# Smallest weight (elements of one layer's matrix) quantize_params codes;
+# routers and norms are far below it and stay in floating point.
+MIN_SIZE = 1 << 16
 
 
 def quantize_rtn(w: jnp.ndarray, group_size: int = 128,
@@ -48,35 +52,45 @@ def quantize_rtn(w: jnp.ndarray, group_size: int = 128,
 
 def dequantize(codes: jnp.ndarray, scale: jnp.ndarray,
                k: int = 0) -> jnp.ndarray:
-    """codes: [Kw, N] (possibly group-padded) -> [k or Kw, N] fp32."""
-    Kw, N = codes.shape
-    G = Kw // scale.shape[0]
-    wg = codes.astype(jnp.float32).reshape(Kw // G, G, N) * scale[:, None, :]
-    w = wg.reshape(Kw, N)
-    return w[:k] if k else w
+    """codes: [..., Kw, N] (possibly group-padded; leading stage axes
+    allowed) -> [..., k or Kw, N] fp32."""
+    *lead, Kw, N = codes.shape
+    G = Kw // scale.shape[-2]
+    wg = (codes.astype(jnp.float32).reshape(*lead, Kw // G, G, N)
+          * scale[..., :, None, :])
+    w = wg.reshape(*lead, Kw, N)
+    return w[..., :k, :] if k else w
 
 
 def quantize_params(params: Params, group_size: int = 128,
                     pow2_scales: bool = True,
-                    min_size: int = 1 << 16) -> Params:
-    """Replace every 2-D linear weight leaf named ``w`` with
-    {w_int, scale} (large matrices only — routers/norms stay fp).
+                    min_size: int = MIN_SIZE) -> Params:
+    """Replace every linear weight leaf named ``w`` with {w_int, scale}
+    (large matrices only — routers/norms stay fp).
 
-    Weights whose input dim is not a group multiple are group-padded by
-    ``quantize_rtn`` (the matmul wrappers zero-pad the activation), so no
-    eligible weight is silently skipped."""
-    def walk(tree):
+    2-D leaves are one layer's [K, N]; the scan-stacked layers under
+    ``stages`` are [S, K, N] and are quantized per stage (the size
+    threshold applies to one stage's matrix), so the codes and scales
+    keep the leading stage axis the scan slices.  Weights whose input
+    dim is not a group multiple are group-padded by ``quantize_rtn``
+    (the matmul wrappers zero-pad the activation), so no eligible weight
+    is silently skipped."""
+    q = functools.partial(quantize_rtn, group_size=group_size,
+                          pow2_scales=pow2_scales)
+
+    def walk(tree, stacked):
         if isinstance(tree, dict):
             out = {}
             for k, v in tree.items():
-                if (k == "w" and hasattr(v, "ndim") and v.ndim == 2
-                        and v.size >= min_size):
-                    codes, scale = quantize_rtn(v, group_size, pow2_scales)
-                    out["w_int"] = codes
-                    out["scale"] = scale
+                if (k == "w" and hasattr(v, "ndim")
+                        and v.ndim == (3 if stacked else 2)
+                        and v.size // (v.shape[0] if stacked else 1)
+                        >= min_size):
+                    out["w_int"], out["scale"] = (jax.vmap(q)(v) if stacked
+                                                  else q(v))
                 else:
-                    out[k] = walk(v)
+                    out[k] = walk(v, stacked or k == "stages")
             return out
         return tree
 
-    return walk(params)
+    return walk(params, False)

@@ -10,8 +10,8 @@ Sources: ``compiled.cost_analysis()`` (flops, bytes accessed) and the
 post-SPMD HLO text (collective operand/result sizes — cost_analysis does not
 cover comm).  All sizes in the partitioned module are per-device.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (assignment-specified).
+Hardware constants come from ``PEAKS``, keyed by the ``device_kind`` JAX
+reports; a kind that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -19,11 +19,28 @@ import json
 import re
 from typing import Dict, Optional, Tuple
 
-HW = {
-    "peak_flops": 197e12,     # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,          # B/s per chip
-    "ici_bw": 50e9,           # B/s per link
+# Per-chip peaks by ``jax.devices()[i].device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# ICI per chip; ~50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,     # bf16 FLOP/s per chip
+        "hbm_bw": 819e9,          # B/s per chip
+        "ici_bw": 50e9,           # B/s per link
+    },
 }
+
+# The chip the dry-run's described production meshes stand for.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peaks recorded for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -93,10 +110,12 @@ def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, float]:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   coll_bytes_per_dev: float) -> Dict[str, float]:
-    t_c = flops_per_dev / HW["peak_flops"]
-    t_m = bytes_per_dev / HW["hbm_bw"]
-    t_x = coll_bytes_per_dev / HW["ici_bw"]
+                   coll_bytes_per_dev: float,
+                   device_kind: str) -> Dict[str, float]:
+    hw = peaks(device_kind)
+    t_c = flops_per_dev / hw["peak_flops"]
+    t_m = bytes_per_dev / hw["hbm_bw"]
+    t_x = coll_bytes_per_dev / hw["ici_bw"]
     terms = {"compute_s": t_c, "memory_s": t_m, "collective_s": t_x}
     dom = max(terms, key=terms.get)
     terms["bottleneck"] = dom.replace("_s", "")
@@ -120,7 +139,7 @@ def memory_analysis_dict(compiled) -> Dict[str, float]:
 
 
 def analyze_compiled(compiled, *, chips: int, model_flops: float,
-                     shape_kind: str) -> Dict:
+                     shape_kind: str, device_kind: str) -> Dict:
     """Full §Roofline record for one compiled cell.
 
     Primary flops/bytes/collective figures come from the loop-aware static
@@ -136,11 +155,12 @@ def analyze_compiled(compiled, *, chips: int, model_flops: float,
     flops = float(static["flops"])
     byts = float(static["bytes"])
     coll_total = float(static["collective_total"])
-    terms = roofline_terms(flops, byts, coll_total)
+    terms = roofline_terms(flops, byts, coll_total, device_kind)
     mem = memory_analysis_dict(compiled)
     useful = model_flops / (flops * chips) if flops else 0.0
     return {
         "chips": chips,
+        "device_kind": device_kind,
         "hlo_flops_per_dev": flops,
         "hlo_bytes_per_dev": byts,
         "collective_bytes_per_dev": coll_total,

@@ -59,6 +59,14 @@ from repro.serve.scheduler import (ActiveRequest, PrefillChunk, Request,
 # default — the deprecation shim only routes *explicit* flat kwargs
 # through EngineConfig.from_kwargs
 _UNSET = object()
+
+# Every program of the continuous-batching engine rounds where the JAX
+# program rounds.  XLA's default excess precision keeps some bf16
+# intermediates of a fusion in f32 (a projection's gate multiply and
+# residual add, the next norm's sum of squares), and a sharded program
+# fuses differently from one device's, so with it a tensor-parallel
+# engine would round differently from the unsharded one.
+COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 _legacy_warned = False
 
 
@@ -652,6 +660,12 @@ class ContinuousBatchingEngine:
         if mesh is not None:
             if cfg.frontend != "token":
                 raise ValueError("sharded serving requires a token frontend")
+            if cfg.use_kernels and jax.default_backend() != "cpu":
+                # off the CPU a Pallas kernel is one Mosaic custom call,
+                # which the SPMD partitioner cannot split
+                raise ConfigError("sharded serving runs the jnp path: "
+                                  "Mosaic kernels cannot be partitioned "
+                                  "automatically (use_kernels=False)")
             pol = sharding_policy or ShardingPolicy(mesh, cfg, mode="serve")
             if pol.mode != "serve":
                 raise ValueError("ContinuousBatchingEngine requires a "
@@ -962,10 +976,12 @@ class ContinuousBatchingEngine:
         run loops can poll total compile-cache growth (the recompile
         counter)."""
         if self.policy is None:
-            jitted = jax.jit(fn, donate_argnums=donate)
+            jitted = jax.jit(fn, donate_argnums=donate,
+                             compiler_options=COMPILER_OPTIONS)
         else:
             jitted = jax.jit(fn, donate_argnums=donate,
-                             in_shardings=in_sh, out_shardings=out_sh)
+                             in_shardings=in_sh, out_shardings=out_sh,
+                             compiler_options=COMPILER_OPTIONS)
         self._jitted.append(jitted)
         return jitted
 
@@ -3538,11 +3554,21 @@ class ContinuousBatchingEngine:
                             break
                         if self._reclaim_pages():
                             continue
+                        # hand back what the longer attempt reserved past
+                        # each resident's fill before shrinking or evicting
+                        for slot in sched.active:
+                            alloc.trim(slot)
                         if n_eff > 1:
                             n_eff //= 2
                             shrunk = True
                             continue
-                        if not self._preempt_youngest(rs, exclude=failed):
+                        # youngest-first over every resident, the failing
+                        # one included: sparing it would evict an older
+                        # request for a younger one, and two residents
+                        # could then evict each other forever
+                        if not self._preempt_youngest(
+                                rs, exclude=(failed if len(sched.active) == 1
+                                             else None)):
                             if hidden:
                                 alloc.unhide_pages(hidden)
                                 hidden = []

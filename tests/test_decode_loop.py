@@ -220,11 +220,14 @@ def test_fused_paged_preemption_identity():
     cfg = _cfg()
     params = _params(cfg)
     prompts = _prompts(cfg, [8, 8], seed=1)
-    _, ud, ref = _run(cfg, params, prompts, [16, 16])
+    _, ud, ref = _run(cfg, params, prompts, [24, 24])
     # 8 pages: enough spare for both prompts to be admitted concurrently
     # (6 would make the epoch reservation defer the second admission and
-    # dodge preemption entirely), yet too few for both to finish resident
-    eng, up, fused = _run(cfg, params, prompts, [16, 16], kv_mode="paged",
+    # dodge preemption entirely), yet too few for both to finish resident.
+    # 24 new tokens each: at 16 the two requests' measured entries (after
+    # history reuse) fit in 8 pages once the epoch shrinks to one step, so
+    # a correct engine never has to preempt.
+    eng, up, fused = _run(cfg, params, prompts, [24, 24], kv_mode="paged",
                           page_size=8, num_pages=8, decode_steps=8)
     assert fused["stats"].preemptions >= 1
     assert fused["stats"].requests_completed == 2

@@ -69,7 +69,7 @@ def test_sharded_train_and_elastic_reshard(tmp_path):
     from repro.train.trainer import Trainer, TrainerConfig
     from repro.train import checkpoint as ck
 
-    from repro.distributed.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     cfg = dataclasses.replace(get_config("qwen3-8b").smoke(), num_layers=2)
     mesh = make_mesh((4, 2), ("data", "model"))
     pol = ShardingPolicy(mesh, cfg, mode="train")
@@ -100,14 +100,14 @@ def test_compressed_mean_shard_map():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.optim.compression import compressed_mean
     mesh = make_mesh((8,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024)) * 0.01
     def f(xs):
         return compressed_mean(xs[0], "data")
-    out = jax.jit(shard_map(f, mesh, in_specs=P("data"),
-                  out_specs=P(), check=False))(x)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                  out_specs=P(), check_vma=False))(x)
     ref = x.mean(axis=0)
     err = float(jnp.abs(out - ref).max())
     assert err < 2e-4, err
@@ -119,7 +119,7 @@ def test_compressed_mean_shard_map():
 def test_pipeline_over_axis():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.distributed.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.pipeline import pipeline_apply
     S, M, mbsz, D = 4, 6, 2, 8
     mesh = make_mesh((4,), ("pod",))
@@ -138,23 +138,11 @@ def test_pipeline_over_axis():
     """)
 
 
-def _skip_unless_abstract_mesh():
-    """The spec-construction tests build device-free production meshes via
-    jax.sharding.AbstractMesh, which the oldest supported jax predates —
-    they skip on that CI matrix leg (and still run, never skip, in the
-    multi-device job, which installs the latest jax)."""
-    from repro.distributed.compat import has_abstract_mesh
-    if not has_abstract_mesh():
-        pytest.skip("jax.sharding.AbstractMesh unavailable on this jax "
-                    "(oldest-pin compat leg)")
-
-
 def test_param_specs_all_archs_production_meshes():
-    _skip_unless_abstract_mesh()
     _run("""
     import jax
     from repro.configs import ASSIGNED_ARCHS, get_config
-    from repro.distributed.compat import abstract_mesh
+    from repro.launch.mesh import abstract_mesh
     from repro.distributed.sharding import ShardingPolicy
     from repro.models import model as M
     from functools import partial
@@ -187,12 +175,11 @@ def test_param_specs_merged_wqkv_and_gu_production_meshes():
     ``wqkv`` gets the column split when the q/kv slices divide the model
     axis, the GQA row-parallel fallback otherwise (never full replication
     of a 2-D weight), and the widened ``[gate|up]`` always column-splits."""
-    _skip_unless_abstract_mesh()
     _run("""
     import jax
     from functools import partial
     from repro.configs import ASSIGNED_ARCHS, get_config
-    from repro.distributed.compat import abstract_mesh
+    from repro.launch.mesh import abstract_mesh
     from repro.distributed.sharding import ShardingPolicy
     from repro.models import model as M
 
@@ -246,12 +233,11 @@ def test_cache_specs_slot_pool_and_paged_store_production_meshes():
     and the paged KV store on the production meshes: KV head axes go over
     ``model``, entry metadata (pos/l0/l1) and everything the host mutates
     stay replicated, and every sharded dim divides its axes exactly."""
-    _skip_unless_abstract_mesh()
     _run("""
     import jax
     from functools import partial
     from repro.configs import get_config
-    from repro.distributed.compat import abstract_mesh
+    from repro.launch.mesh import abstract_mesh
     from repro.distributed.sharding import ShardingPolicy
     from repro.kvcache import paged as paged_mod
     from repro.models import model as M
@@ -299,3 +285,20 @@ def test_cache_specs_slot_pool_and_paged_store_production_meshes():
         jax.tree_util.tree_map_with_path(check, store, st_sh)
     print("cache specs ok")
     """)
+
+
+@pytest.mark.parametrize("mode", ["train", "serve"])
+def test_contracted_pin_only_at_serve_time(mode):
+    """Serve mode pins a contracted projection input whole; training
+    leaves it to the partitioner (Megatron row-parallel wo/down)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.distributed.sharding import ShardingPolicy
+    from repro.launch.mesh import abstract_mesh
+
+    pol = ShardingPolicy(abstract_mesh((("data", 2), ("model", 4))),
+                         get_config("llama2-7b"), mode=mode)
+    want = None if mode == "train" else P(("data",), None, None)
+    assert pol.spec("contracted") == want
+

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.roofline.hlo_cost import hlo_static_cost
-from repro.roofline.analysis import roofline_terms, HW
+from repro.roofline.analysis import PEAKS, peaks, roofline_terms
 
 
 def test_scan_flops_match_unrolled():
@@ -58,9 +58,20 @@ def test_bf16_upcast_normalization():
 
 
 def test_roofline_terms_bottleneck():
-    t = roofline_terms(HW["peak_flops"], 0.0, 0.0)
+    kind = "TPU v5 lite"
+    hw = peaks(kind)
+    t = roofline_terms(hw["peak_flops"], 0.0, 0.0, kind)
     assert t["bottleneck"] == "compute" and abs(t["compute_s"] - 1.0) < 1e-9
-    t = roofline_terms(0.0, HW["hbm_bw"], 0.0)
+    t = roofline_terms(0.0, hw["hbm_bw"], 0.0, kind)
     assert t["bottleneck"] == "memory"
-    t = roofline_terms(0.0, 0.0, HW["ici_bw"])
+    t = roofline_terms(0.0, 0.0, hw["ici_bw"], kind)
     assert t["bottleneck"] == "collective"
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v5"])
+def test_peaks_unknown_device_kind_raises(kind):
+    assert kind not in PEAKS
+    with pytest.raises(ValueError, match="no peaks recorded"):
+        peaks(kind)
+    with pytest.raises(ValueError):
+        roofline_terms(1.0, 1.0, 1.0, kind)

@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.models import model as M
@@ -101,3 +102,102 @@ def test_quantized_model_close_to_dense():
     # int4 weights perturb logits but preserve the distribution's shape
     corr = np.corrcoef(d.ravel(), q.ravel())[0, 1]
     assert corr > 0.9
+
+
+# ---------------------------------------------------------------------------
+# Layer-by-layer init and quantization of the scan-stacked layers
+# ---------------------------------------------------------------------------
+
+def _wide_cfg():
+    """Smoke depth, but wide enough that every linear weight of a layer
+    (not the routers) clears quantize_params' size threshold."""
+    return dataclasses.replace(get_config("llama2-7b").smoke(),
+                               d_model=256, d_ff=512)
+
+
+def _reference_init(key, cfg):
+    """The pre-stage-streaming init: one vmap over every stacked stage."""
+    from repro.models import layers, transformer
+    ks = jax.random.split(key, 4)
+    p = {"embed": layers.embedding_init(ks[0], cfg),
+         "stack": transformer.stack_init(ks[1], cfg),
+         "final_norm": layers.norm_init(cfg.d_model, cfg)}
+    if not cfg.tie_embeddings:
+        p["lm_head"] = layers.linear_init(ks[2], cfg.d_model,
+                                          cfg.vocab_size, cfg, scale=0.02)
+    return p
+
+
+@pytest.mark.parametrize("arch", ["llama2-7b", "qwen3-8b", "gemma3-12b",
+                                  "jamba-v0.1-52b"])
+def test_stage_streamed_init_matches_vmapped_init(arch):
+    cfg = get_config(arch).smoke()
+    got = M.init_params(KEY, cfg)
+    want = _reference_init(KEY, cfg)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def test_every_stacked_linear_leaf_is_quantized():
+    cfg = _wide_cfg()
+    assert cfg.num_stages > 1
+    qp = M.init_params(KEY, cfg, quantize=True)
+    n_stacked = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(qp):
+        keys = [getattr(k, "key", "") for k in path]
+        if keys[-1] == "w":
+            assert keys[-2] == "router", f"dense linear left: {keys}"
+        if "stages" in keys and keys[-1] in ("w_int", "scale"):
+            n_stacked += 1
+            assert leaf.ndim == 3 and leaf.shape[0] == cfg.num_stages - 1
+            if keys[-1] == "w_int":
+                assert leaf.dtype == jnp.int8
+                assert int(leaf.min()) >= -8 and int(leaf.max()) <= 7
+    assert n_stacked >= 8         # wqkv, wo, gu, down: codes + scales
+
+
+def test_quantize_at_init_equals_quantize_params():
+    cfg = _wide_cfg()
+    a = M.init_params(KEY, cfg, quantize=True)
+    b = quantize_params(M.init_params(KEY, cfg), cfg.quant.group_size,
+                        cfg.quant.pow2_scales)
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+    for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _dequantized_twin(params, dtype):
+    """Every {w_int, scale} back to a dense ``w`` (stage axes kept)."""
+    if not isinstance(params, dict):
+        return params
+    if "w_int" in params:
+        return {"w": dequantize(params["w_int"], params["scale"]).astype(
+            dtype)}
+    return {k: _dequantized_twin(v, dtype) for k, v in params.items()}
+
+
+def test_quantized_model_decodes_like_dequantized_twin():
+    """pow2-scaled int4 codes are exact in bf16, so the jnp path of the
+    quantized model and of its dequantized twin must pick the same greedy
+    tokens — through the stacked layers' per-stage w_int/scale slices."""
+    from repro.serve.engine import ContinuousBatchingEngine
+
+    cfg = _wide_cfg()
+    qp = M.init_params(KEY, cfg, quantize=True)
+    twin = _dequantized_twin(qp, jnp.dtype(cfg.dtype))
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab_size, (n,), dtype=np.int32)
+               for n in (9, 14)]
+    tokens = []
+    for p in (qp, twin):
+        eng = ContinuousBatchingEngine(cfg, p, max_slots=2, max_len=32,
+                                       kv_mode="paged")
+        uids = [eng.submit(x, 8) for x in prompts]
+        res = eng.run()["results"]
+        tokens.append([np.asarray(res[u].tokens).tolist() for u in uids])
+    assert tokens[0] == tokens[1]

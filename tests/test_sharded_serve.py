@@ -22,7 +22,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.routing import neutral_router_bias
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.serve.engine import ContinuousBatchingEngine
 
