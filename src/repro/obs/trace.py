@@ -6,19 +6,30 @@ The serving engines record two families of spans into one timeline
 * **engine track** (tid 0): one ``step`` span per run-loop iteration with
   ``plan`` / ``prefill`` / ``dispatch`` / ``sync`` / ``bookkeep``
   children — the host-side phase breakdown of every engine iteration —
-  plus ``C`` counter series (queue depth, resident slots, free pages)
-  and ``compile`` instants whenever a jitted dispatch added a new
-  compiled variant (how pow2-epoch recompiles become visible).
+  plus ``C`` counter series (``sched``: queue depth, resident slots,
+  free pages; ``kv_walk``: the paged walk's entries; ``admission``:
+  deferred admissions) and ``compile`` instants whenever a jitted
+  dispatch added a new compiled variant (how pow2-epoch recompiles
+  become visible).
 * **request tracks** (tid = 1 + uid): the per-request lifecycle
   ``request ⊃ queued → prefill[chunk i] → decode[epoch j] → finish``,
   with ``preempt``/``requeue`` instants when paged backpressure evicts
-  the request back into the queue.
+  the request back into the queue and one ``first_token`` instant when
+  its first token is handed out.
 
 Spans are emitted as matched ``"B"``/``"E"`` duration events (the
 begin/end pairing is what ``tools/trace_summary.py`` and the schema test
 validate); counters are ``"C"`` events and instants ``"i"``.  Timestamps
 are microseconds of ``time.perf_counter`` since tracer creation —
 monotonic, never NTP-skewed.
+
+Every engine-track span opened live (``begin``/``span``, not the
+after-the-fact ``span_at``) is mirrored into a
+``jax.profiler.TraceAnnotation`` of the same name, opened and closed
+with it, so a ``jax.profiler`` capture holds the engine's phases on the
+profiler's own clock, around the device work they launch.  Request-track
+spans are not mirrored: they interleave across requests, and profiler
+annotations on one thread must nest.
 
 ``NullTracer`` is the always-off twin every engine holds by default: the
 same API as no-op methods, so the run loops trace unconditionally and
@@ -37,6 +48,7 @@ import jax
 
 ENGINE_TID = 0          # the engine run-loop track
 _PID = 1                # single logical process
+_LAST: Optional["Tracer"] = None
 
 
 def request_tid(uid: int) -> int:
@@ -60,9 +72,12 @@ class Tracer:
         self.events: List[dict] = []
         self._open: Dict[int, List[str]] = {}     # tid -> open span names
         self._named: set = set()                  # tids with thread_name set
+        self._mirror: list = []                   # engine-track annotations
         self._event({"name": "process_name", "ph": "M", "pid": _PID, "tid": 0,
                      "args": {"name": "skipopu-serve"}})
         self.track(ENGINE_TID, "engine")
+        global _LAST
+        _LAST = self
 
     # -- primitives --------------------------------------------------------
     def now_us(self) -> float:
@@ -92,6 +107,12 @@ class Tracer:
         if args:
             ev["args"] = args
         self._event(ev)
+        if tid == ENGINE_TID:
+            ann = None
+            if ts is None:
+                ann = jax.profiler.TraceAnnotation(name)
+                ann.__enter__()
+            self._mirror.append(ann)
 
     def end(self, tid: int = ENGINE_TID, ts: Optional[float] = None,
             **args) -> None:
@@ -99,6 +120,10 @@ class Tracer:
         if not stack:
             raise RuntimeError(f"Tracer.end on tid {tid} with no open span")
         name = stack.pop()
+        if tid == ENGINE_TID:
+            ann = self._mirror.pop()
+            if ann is not None:
+                ann.__exit__(None, None, None)
         ev = {"name": name, "ph": "E", "pid": _PID, "tid": tid,
               "ts": self.now_us() if ts is None else ts}
         if args:
@@ -133,12 +158,6 @@ class Tracer:
         self._event({"name": name, "ph": "C", "pid": _PID, "tid": tid,
                      "ts": self.now_us(), "args": dict(values)})
 
-    def annotate(self, name: str):
-        """Context wrapping a jitted dispatch in a
-        ``jax.profiler.TraceAnnotation`` so device-side profiles carry
-        the engine's phase names too."""
-        return jax.profiler.TraceAnnotation(name)
-
     # -- output ------------------------------------------------------------
     def open_spans(self) -> Dict[int, List[str]]:
         """Unclosed spans per tid (should be empty after a drained run)."""
@@ -157,8 +176,8 @@ class Tracer:
 
 
 class NullTracer(Tracer):
-    """The off switch: same API, records nothing, ``annotate`` is a
-    no-op context.  The engines hold one of these unless ``trace=`` was
+    """The off switch: same API, records nothing and opens no profiler
+    annotation.  The engines hold one of these unless ``trace=`` was
     passed, so tracing calls stay on the hot path unconditionally."""
 
     enabled = False
@@ -190,14 +209,21 @@ class NullTracer(Tracer):
     def counter(self, name, values, tid=ENGINE_TID):
         pass
 
-    def annotate(self, name):
-        return contextlib.nullcontext()
-
     def now_us(self) -> float:
         return 0.0
 
     def to_us(self, t: float) -> float:
         return 0.0
+
+
+def last_tracer() -> Optional[Tracer]:
+    """The most recently constructed enabled ``Tracer``, or None.
+
+    For operator code that sees a process's trace but not the engine
+    that records it: an exporter that ships the spans and counters of
+    the live tracer, or a debug hook that reads them after a failure.
+    ``NullTracer`` never registers."""
+    return _LAST
 
 
 def as_tracer(trace) -> Tracer:

@@ -211,9 +211,9 @@ class RequestResult:
       uid          — id returned by ``submit``.
       tokens       — generated token ids, stop token (if hit) included.
       prompt_len   — real prompt length T0 (padding excluded).
-      ttft_s       — wall seconds from ``run()`` start (every request is
-                     considered submitted when the run starts) to this
-                     request's first token.  Under monolithic prefill
+      ttft_s       — wall seconds from the request's ``submit`` to the
+                     first token of its last admission (a preempted
+                     request re-prefills).  Under monolithic prefill
                      that is queue wait + one prefill; under chunked
                      prefill (``prefill_chunk > 0``) it spans all
                      ceil(T0/chunk) chunk steps *plus* the decode steps
@@ -1494,9 +1494,13 @@ class ContinuousBatchingEngine:
         the mark does not), so every emitted index streams exactly once —
         at temperature 0 a preempted request re-derives the identical
         prefix; at temperature > 0 re-decoded tokens may diverge from
-        what was already streamed (documented caveat)."""
+        what was already streamed (documented caveat).  The request's
+        ``first_token`` instant fires when the mark leaves 0, so once per
+        request, preemptions included."""
         w = self._stream_pos.get(uid, 0)
         if len(out_tokens) > w:
+            if not w:
+                self.tracer.instant("first_token", request_tid(uid))
             buf = self._streams.setdefault(uid, [])
             buf.extend((int(t), step) for t in out_tokens[w:])
             self._stream_pos[uid] = len(out_tokens)
@@ -1597,6 +1601,54 @@ class ContinuousBatchingEngine:
         tr.end(tid)                       # queued
         tr.instant("admit", tid, slot=pf.slot)
         tr.begin("prefill", tid)
+
+    def _count_deferrals(self, rs: _RunState) -> None:
+        """Call once per iteration after its planning: the requests left
+        queued while a slot stays free (``min(free slots, queued)``),
+        counted by why — ``one_per_iteration`` when a prefill was
+        admitted or is in flight, ``pages`` when ``can_place`` refused
+        the queue's head — plus the ``admission`` trace counter row."""
+        sched, m = self.scheduler, rs.metrics
+        n = min(sched.free_slots, len(sched.queue))
+        if n:
+            m.inc("admissions_deferred_total", n,
+                  reason=("one_per_iteration" if sched.prefilling
+                          is not None else "pages"))
+        tr = self.tracer
+        if tr.enabled:
+            tr.counter("admission", {
+                r: m.value("admissions_deferred_total", reason=r)
+                for r in ("one_per_iteration", "pages")})
+
+    def _walk_state(self, j: int) -> Dict[str, int]:
+        """The residents before a paged decode dispatch: how many, their
+        positions and their stored entries summed, and ``J``, the
+        block-table width in pages the attention walks for every slot
+        (the ``dispatch`` span's arguments)."""
+        active, fill = self.scheduler.active, self.allocator.fill
+        return {"residents": len(active),
+                "positions": sum(st.pos for st in active.values()),
+                "entries": sum(int(fill[s]) for s in active), "J": j}
+
+    def _count_walk(self, rs: _RunState, steps: int, j: int, live: int,
+                    valid: int) -> None:
+        """Entries the paged decode walks, after ``steps`` dispatched
+        steps: each step reads every slot's block table up to ``j``
+        pages at each attention layer (``walked``); ``live`` and
+        ``valid`` are the fills and positions of the slots active in
+        each step, summed over the steps (the chain holds ``fill``
+        entries, of which each layer needs one per position).  Plus the
+        ``kv_walk`` trace counter row of the cumulative values."""
+        m, nA = rs.metrics, self.n_attn
+        name = "paged_walk_entries_total"
+        m.inc(name, nA * self.max_slots * j * self.page_size * steps,
+              part="walked")
+        m.inc(name, nA * live, part="live")
+        m.inc(name, nA * valid, part="valid")
+        tr = self.tracer
+        if tr.enabled:
+            tr.counter("kv_walk", {p: m.value(name, part=p)
+                                   for p in ("walked", "live", "valid")})
 
     def _poll_compiles(self, rs: _RunState) -> None:
         """Surface jit-cache growth (new prefill buckets, pow2 epoch
@@ -1984,7 +2036,7 @@ class ContinuousBatchingEngine:
         rs.metrics.inc("decode_tokens_total")
         st = ActiveRequest(req=req, slot=slot, pos=req.prompt_len,
                            next_token=tok, out_tokens=[tok],
-                           submit_s=rs.t_run, first_token_s=now,
+                           submit_s=req.submit_s, first_token_s=now,
                            last_emit_s=now)
         self.scheduler.activate(st)
         if tok_known and req.stop_token is not None \
@@ -2124,8 +2176,7 @@ class ContinuousBatchingEngine:
         tr = self.tracer
         tid = request_tid(work.req.uid)
         if not self.prefill_chunk:
-            with tr.span("prefill[0]", tid, tokens=work.req.prompt_len), \
-                    tr.annotate("prefill"):
+            with tr.span("prefill[0]", tid, tokens=work.req.prompt_len):
                 padded, last = self.scheduler.pad_prompt(work.req.tokens)
                 rs.rng, sub = jax.random.split(rs.rng)
                 tok_dev, cache, pstats = self._prefill(
@@ -2137,8 +2188,7 @@ class ContinuousBatchingEngine:
                 pf_gates = pf_gates[:, 0]                         # [L, Tp]
         else:
             idx = work.start // self.prefill_chunk
-            with tr.span(f"prefill[{idx}]", tid, tokens=len(work.tokens)), \
-                    tr.annotate("prefill_chunk"):
+            with tr.span(f"prefill[{idx}]", tid, tokens=len(work.tokens)):
                 logits = self._chunk_forward(rs, work)
             if not work.is_last:
                 # no sync: the chunk's compute overlaps the decode step
@@ -2177,8 +2227,7 @@ class ContinuousBatchingEngine:
         tid = request_tid(req.uid)
         if not self.prefill_chunk:
             T0 = req.prompt_len
-            with tr.span("prefill[0]", tid, tokens=T0), \
-                    tr.annotate("prefill_paged"):
+            with tr.span("prefill[0]", tid, tokens=T0):
                 padded, last = self.scheduler.pad_prompt(req.tokens)
                 rs.rng, sub = jax.random.split(rs.rng)
                 tok_dev, cache, pstats = self._prefill_paged(
@@ -2190,8 +2239,7 @@ class ContinuousBatchingEngine:
             # _run_paged (the reservation must not trail the _can_place
             # check across iterations)
             idx = work.start // self.prefill_chunk
-            with tr.span(f"prefill[{idx}]", tid, tokens=len(work.tokens)), \
-                    tr.annotate("prefill_chunk"):
+            with tr.span(f"prefill[{idx}]", tid, tokens=len(work.tokens)):
                 logits = self._chunk_forward(rs, work)
             if not work.is_last:
                 # no sync: chunk compute overlaps this iteration's decode
@@ -2252,12 +2300,10 @@ class ContinuousBatchingEngine:
         if work.is_first:
             if warm.copy is not None:
                 src, dst, keep = warm.copy
-                with tr.span("cow_copy", tid, entries=keep), \
-                        tr.annotate("cow_copy"):
+                with tr.span("cow_copy", tid, entries=keep):
                     store = self._cow_copy(store, jnp.int32(src),
                                            jnp.int32(dst), jnp.int32(keep))
-            with tr.span("warm_restore", tid, tokens=Ts, entries=E_s), \
-                    tr.annotate("warm_restore"):
+            with tr.span("warm_restore", tid, tokens=Ts, entries=E_s):
                 rs.stage_cache = self._warm_cache(
                     store, jnp.asarray(alloc.block_table[slot]),
                     jnp.int32(E_s))
@@ -2277,8 +2323,7 @@ class ContinuousBatchingEngine:
             if Ts + width > self._warm_cap:
                 width = c
             idx = 0
-        with tr.span(f"prefill[{idx}]", tid, tokens=c, warm=Ts), \
-                tr.annotate("prefill_chunk"):
+        with tr.span(f"prefill[{idx}]", tid, tokens=c, warm=Ts):
             logits = self._chunk_forward(rs, work, width=width)
         if not work.is_last:
             m.inc("prefill_chunks_total")
@@ -2362,6 +2407,7 @@ class ContinuousBatchingEngine:
                 did_prefill = True
                 if self.prefill_chunk:
                     break
+            self._count_deferrals(rs)
             if did_prefill and pre_active:
                 m.inc("interleaved_steps_total")
 
@@ -2378,7 +2424,7 @@ class ContinuousBatchingEngine:
                 pos[slot] = st.pos
             t0 = perf_counter()
             try:
-                with tr.span("dispatch"), tr.annotate("decode_step"):
+                with tr.span("dispatch"):
                     self._fault_dispatch(rs)
                     logits, pool, dstats = self._decode(
                         self.params, pool,
@@ -2582,6 +2628,7 @@ class ContinuousBatchingEngine:
                 plan = sched.plan_step(can_place=self._can_place,
                                        token_budget=self.step_tokens)
             self._note_admission(rs)
+            self._count_deferrals(rs)
             # reserve a newly admitted prompt's worst-case pages NOW,
             # inside the same iteration as its _can_place check: chunked
             # execution and budget deferrals can postpone the first
@@ -2624,9 +2671,10 @@ class ContinuousBatchingEngine:
             j_live = max(1, alloc.max_chain_pages())
             j_step = min(1 << (j_live - 1).bit_length(),
                          alloc.pages_per_slot)
+            walk = self._walk_state(j_step)
             t0 = perf_counter()
             try:
-                with tr.span("dispatch"), tr.annotate("paged_decode_step"):
+                with tr.span("dispatch", n=1, **walk):
                     self._fault_dispatch(rs)
                     logits, store, dstats = self._decode_paged(
                         self.params, store,
@@ -2646,6 +2694,8 @@ class ContinuousBatchingEngine:
                 yield
                 continue
             m.inc("decode_dispatches_total")
+            self._count_walk(rs, 1, j_step, walk["entries"],
+                             walk["positions"])
             t_sync = perf_counter()
             with tr.span("sync"):
                 self._fault_stall(rs)
@@ -2914,6 +2964,7 @@ class ContinuousBatchingEngine:
                 did_prefill = True
                 if self.prefill_chunk:
                     break
+            self._count_deferrals(rs)
             if did_prefill and pre_active:
                 m.inc("interleaved_steps_total")
 
@@ -2936,7 +2987,7 @@ class ContinuousBatchingEngine:
                 feed_dev = jnp.asarray(feed)
                 pos_dev = jnp.asarray(pos)
                 dout = None
-                with tr.span("draft", k=gamma), tr.annotate("spec_draft"):
+                with tr.span("draft", k=gamma):
                     self._fault_dispatch(rs)
                     if gamma:
                         pool, dout = self._spec_draft(gamma)(
@@ -2949,7 +3000,7 @@ class ContinuousBatchingEngine:
                             feed_chunk = self._override_drafts(feed, dout)
                     else:
                         feed_chunk = feed_dev[:, None]
-                with tr.span("verify", k=gamma), tr.annotate("spec_verify"):
+                with tr.span("verify", k=gamma):
                     tgt_dev, vlog_dev, pool, vstats = self._spec_verify()(
                         self.params, pool, {"tokens": feed_chunk}, pos_dev)
             except FaultInjected:
@@ -3096,6 +3147,7 @@ class ContinuousBatchingEngine:
                 plan = sched.plan_step(can_place=self._can_place,
                                        token_budget=self.step_tokens)
             self._note_admission(rs)
+            self._count_deferrals(rs)
             pf = sched.prefilling
             if (pf is not None and pf.done == 0
                     and (self.prefill_chunk
@@ -3150,7 +3202,7 @@ class ContinuousBatchingEngine:
                 feed_dev = jnp.asarray(feed)
                 pos_dev = jnp.asarray(pos)
                 dout = None
-                with tr.span("draft", k=gamma), tr.annotate("spec_draft"):
+                with tr.span("draft", k=gamma):
                     self._fault_dispatch(rs)
                     if gamma:
                         store, dout = self._spec_draft(gamma)(
@@ -3163,7 +3215,7 @@ class ContinuousBatchingEngine:
                             feed_chunk = self._override_drafts(feed, dout)
                     else:
                         feed_chunk = feed_dev[:, None]
-                with tr.span("verify", k=gamma), tr.annotate("spec_verify"):
+                with tr.span("verify", k=gamma):
                     if fused:
                         caps = self._emission_caps(cur)
                         store, tgt_dev, gates_dev, committed_dev = (
@@ -3430,8 +3482,7 @@ class ContinuousBatchingEngine:
                         feed_dev = feed_dev.at[slot].set(tok_dev[0])
                 t_disp = perf_counter()
                 try:
-                    with tr.span("dispatch", n=n_eff), \
-                            tr.annotate("decode_epoch"):
+                    with tr.span("dispatch", n=n_eff):
                         self._fault_dispatch(rs)
                         pool, out = self._dense_loop(n_eff)(
                             self.params, pool, feed_dev, jnp.asarray(pos),
@@ -3465,6 +3516,7 @@ class ContinuousBatchingEngine:
                     did_prefill = True
                     if self.prefill_chunk:
                         break
+            self._count_deferrals(rs)
             if did_prefill and pre_active:
                 m.inc("interleaved_steps_total")
 
@@ -3511,8 +3563,11 @@ class ContinuousBatchingEngine:
 
         store = self._apply_resume(rs, self._acquire_store())
         t_loop = perf_counter()
+        walk = [0, 0]             # fills and positions the epoch's steps walk
 
         def per_step(slot, g):
+            walk[0] += int(alloc.fill[slot])
+            walk[1] += sched.active[slot].pos
             fresh_n = int(1 + (g[1:] > 0.5).sum()) if reuse else nA
             alloc.append(slot, fresh_n, nA)
             rs.hist.on_decode_step(slot, g)
@@ -3602,8 +3657,8 @@ class ContinuousBatchingEngine:
                              alloc.pages_per_slot)
                 t_disp = perf_counter()
                 try:
-                    with tr.span("dispatch", n=n_eff), \
-                            tr.annotate("paged_decode_epoch"):
+                    with tr.span("dispatch", n=n_eff,
+                                 **self._walk_state(j_step)):
                         self._fault_dispatch(rs)
                         store, out = self._paged_loop(n_eff)(
                             self.params, store, jnp.asarray(feed),
@@ -3632,6 +3687,7 @@ class ContinuousBatchingEngine:
                                        token_budget=self.step_tokens,
                                        decode_steps=n_eff)
             self._note_admission(rs)
+            self._count_deferrals(rs)
             pf = sched.prefilling
             if (pf is not None and pf.done == 0
                     and (self.prefill_chunk
@@ -3656,7 +3712,9 @@ class ContinuousBatchingEngine:
                 yield
                 continue
 
+            walk[:] = [0, 0]
             self._process_epoch(rs, out, slots, t_disp, per_step=per_step)
+            self._count_walk(rs, n_eff, j_step, *walk)
             self._poll_compiles(rs)
             tr.end()                      # step
             self._drain_stream(rs)

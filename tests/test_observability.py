@@ -138,14 +138,59 @@ def test_tracer_unbalanced_end_raises():
         tr.end()
 
 
-def test_null_tracer_records_nothing():
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter
+    and exit by name, in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kw):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Ann()
+
+
+def test_null_tracer_records_nothing(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
     tr = as_tracer(None)
     assert isinstance(tr, NullTracer) and not tr.enabled
     with tr.span("x"):
         tr.instant("y")
-        with tr.annotate("z"):
-            pass
+        tr.begin("z")
+        tr.end()
     assert tr.events == [] and tr.open_spans() == {}
+    assert ann.log == []                  # no profiler annotation either
+
+
+def test_engine_spans_mirror_into_profiler_annotations(monkeypatch):
+    """Engine-track spans opened live open an annotation of the same
+    name and close it at their end; request-track spans and spans
+    emitted after the fact open none."""
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    tr = Tracer()
+    tr.begin("step", idx=0)
+    with tr.span("dispatch", n=8):
+        tr.begin("request", 5)
+    with tr.span("sync"):
+        pass
+    tr.span_at("decode[0]", 0, 1.0, 2.0)
+    tr.end(5)
+    tr.end()
+    assert ann.log == [("enter", "step"), ("enter", "dispatch"),
+                       ("exit", "dispatch"), ("enter", "sync"),
+                       ("exit", "sync"), ("exit", "step")]
+    assert tr.open_spans() == {}
 
 
 def test_as_tracer_path_roundtrip(tmp_path):
