@@ -23,9 +23,9 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from functools import partial
+from functools import partial, wraps
 from time import perf_counter
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from repro.configs.base import LOCAL, ModelConfig
 from repro.core import kv_reuse
 from repro.core.routing import draft_router_bias
 from repro.distributed.sharding import ShardingPolicy, set_policy
+from repro.kernels import fused_linear as fused_linear_mod
 from repro.kvcache import history as history_mod
 from repro.kvcache import paged as paged_mod
 from repro.models import model as model_lib
@@ -654,6 +655,10 @@ class ContinuousBatchingEngine:
         self.tracer = as_tracer(trace)
         self.metrics: Optional[MetricsRegistry] = None   # last run's registry
         self._jitted: List = []          # every jitted step (compile probe)
+        # the tiles each traced fused-linear call shape ran with, keyed by
+        # ("MxKxN", jitted step name); filled as the steps trace
+        self._linear_plans: Dict[Tuple[str, str],
+                                 fused_linear_mod.FusedLinearPlan] = {}
         self.mesh = mesh
         self.policy: Optional[ShardingPolicy] = None
         self._param_sh = self._repl = None
@@ -974,12 +979,23 @@ class ContinuousBatchingEngine:
         rejects kwargs once shardings are pinned, so callers thread every
         argument positionally).  Every jitted step is registered so the
         run loops can poll total compile-cache growth (the recompile
-        counter)."""
+        counter).  Each fused-linear call the step traces records its
+        tile plan under the step's name."""
+        plans = self._linear_plans
+        name = getattr(fn, "func", fn).__name__       # partials too
+        phase = name.strip("_")
+
+        @wraps(fn)
+        def traced(*args, **kwargs):
+            with fused_linear_mod.recording(plans, phase):
+                return fn(*args, **kwargs)
+
+        traced.__name__ = name          # the program keeps the step's name
         if self.policy is None:
-            jitted = jax.jit(fn, donate_argnums=donate,
+            jitted = jax.jit(traced, donate_argnums=donate,
                              compiler_options=COMPILER_OPTIONS)
         else:
-            jitted = jax.jit(fn, donate_argnums=donate,
+            jitted = jax.jit(traced, donate_argnums=donate,
                              in_shardings=in_sh, out_shardings=out_sh,
                              compiler_options=COMPILER_OPTIONS)
         self._jitted.append(jitted)
@@ -1558,6 +1574,7 @@ class ContinuousBatchingEngine:
                        rng=rng, hist=hist)
         rs.compiled_seen = jit_cache_size(self._jitted)
         self.metrics = rs.metrics
+        self._export_linear_plans(rs.metrics)
         # credit submit-time load sheds to this run's registry (each one
         # already emitted its "shed" trace instant at submit)
         for _ in self._shed_pending:
@@ -1659,6 +1676,20 @@ class ContinuousBatchingEngine:
             rs.metrics.inc("compiles_total", n - rs.compiled_seen)
             self.tracer.instant("compile", n_new=n - rs.compiled_seen)
             rs.compiled_seen = n
+            self._export_linear_plans(rs.metrics)
+
+    def _export_linear_plans(self, m: MetricsRegistry) -> None:
+        """The fused-linear tiles traced so far, into the registry (on a
+        new run and after a compile, never per step): grid steps per call
+        shape and step (``fused_linear_grid_steps{call,phase}``) and how
+        many call shapes found no lane-aligned tile
+        (``fused_linear_plan_fallbacks_total``)."""
+        plans = self._linear_plans
+        for (call, phase), plan in plans.items():
+            m.set("fused_linear_grid_steps", plan.steps, call=call,
+                  phase=phase)
+        m.set("fused_linear_plan_fallbacks_total",
+              sum(p.fallback for p in plans.values()))
 
     def _record_step_series(self, rs: _RunState, lay_keep) -> None:
         """Per-step telemetry time series: per-layer attention-gate keep

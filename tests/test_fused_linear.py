@@ -14,6 +14,8 @@ import pytest
 from repro.configs import get_config
 from repro.core import routing
 from repro.kernels import ops, ref
+from repro.kernels import fused_linear as fl
+from repro.kernels.fused_linear import fused_linear_pallas, fused_linear_plan
 from repro.models import layers
 from repro.models import model as M
 from repro.quant import quantize_params, quantize_rtn
@@ -36,7 +38,10 @@ def _mx(a, b):
 # ---------------------------------------------------------------------------
 
 def _check_case(seed: int, M_: int, K: int, F: int, prologue: bool,
-                int4: bool, epilogue: str, act, group: int = 64):
+                int4: bool, epilogue: str, act, group: int = 64,
+                tiles=None):
+    """Kernel against oracle; ``tiles`` = (bm, bn, bk) calls the kernel
+    with those tiles in place of the planned ones."""
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal((M_, K)),
                     jnp.float32).astype(jnp.bfloat16)
@@ -65,7 +70,12 @@ def _check_case(seed: int, M_: int, K: int, F: int, prologue: bool,
         params = {"w": w}
         args = dict(w=w)
 
-    out, sq = ops.fused_linear(params, x, **kw)
+    if tiles is None:
+        out, sq = ops.fused_linear(params, x, **kw)
+    else:
+        bm, bn, bk = tiles
+        out, sq = fused_linear_pallas(x, **args, **kw, bm=bm, bn=bn, bk=bk,
+                                      interpret=True)
     oref, sq_ref = ref.fused_linear_ref(x, **args, **kw)
     scale_mag = max(1.0, float(jnp.abs(oref.astype(jnp.float32)).max()))
     assert _mx(out, oref) <= 1e-4 * scale_mag
@@ -86,6 +96,9 @@ _GRID = [
     (5, 70, 300, 96, True, True, "none", "gelu", 128),
     (6, 33, 64, 130, False, False, "residual", "gelu", 64),
     (7, 16, 200, 32, True, False, "none", None, 64),
+    # an odd [gate | up] taken whole as one joint block: padded to
+    # 128-wide tiles, its dense dot misses the bound
+    (2625, 58, 300, 130, False, False, "glu", "gelu", 32),
 ]
 
 
@@ -113,6 +126,207 @@ if HAVE_HYPOTHESIS:
             int4=data.draw(st.booleans()),
             epilogue=epilogue, act=act,
             group=data.draw(st.sampled_from([32, 64, 128])))
+
+
+# K steps of several quantization groups, several K steps and column
+# blocks (the scale row of group g in step k is k·(bk/G) + g), row blocks
+# with padding, and the Σy² carry across column blocks
+_MULTIGROUP = [
+    # seed, M, K, F, prologue, epilogue, act, group, tiles (None: planned)
+    (10, 16, 1024, 256, True, "glu", "silu", 128, None),
+    (11, 40, 1024, 384, False, "residual", None, 128, (40, 128, 256)),
+    (12, 24, 768, 256, True, "glu", "gelu", 64, (24, 128, 384)),
+    (13, 300, 512, 128, True, "none", "silu", 128, (128, 128, 256)),
+    (14, 16, 2048, 512, True, "residual", "gelu", 128, None),
+]
+
+
+@pytest.mark.parametrize("seed,M_,K,F,prologue,epilogue,act,group,tiles",
+                         _MULTIGROUP)
+def test_multigroup_k_steps_match_oracle(seed, M_, K, F, prologue, epilogue,
+                                         act, group, tiles):
+    if tiles is None:
+        plan = fused_linear_plan(M_, K, F, group, epilogue == "glu", True)
+        assert plan.bk > group
+    else:
+        assert tiles[2] > group
+    _check_case(seed, M_, K, F, prologue, True, epilogue, act, group,
+                tiles=tiles)
+
+
+_BITWISE = [
+    # M, K, F, epilogue
+    (16, 1024, 512, "glu"),
+    (16, 1024, 640, "residual"),
+    (64, 2048, 256, "none"),
+]
+
+
+@pytest.mark.parametrize("M_,K,F,epilogue", _BITWISE)
+def test_planned_int4_tiles_equal_one_group_tiles(M_, K, F, epilogue):
+    """Several groups per K step keep each (row, group)'s arithmetic and
+    the order of the f32 accumulation, and Σy² adds 128-column chunks in
+    column order: output and Σy² equal those of 128-wide column tiles
+    with one group per K step bit for bit."""
+    G = 128
+    rng = np.random.default_rng(M_ + K + F)
+    glu = epilogue == "glu"
+    x = jnp.asarray(rng.standard_normal((M_, K)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((K, 2 * F if glu else F)) * 0.04,
+                    jnp.float32)
+    codes, scale = quantize_rtn(w, G, pow2_scales=True)
+    kw = dict(w_codes=codes, scale=scale, glu=glu,
+              act="silu" if glu else None, interpret=True,
+              mean_sq=jnp.asarray((np.asarray(x, np.float32) ** 2).mean(-1)),
+              gamma=jnp.asarray(1 + 0.1 * rng.standard_normal(K),
+                                jnp.bfloat16))
+    if epilogue == "residual":
+        kw.update(residual=jnp.asarray(rng.standard_normal((M_, F)),
+                                       jnp.bfloat16),
+                  gate_mul=jnp.asarray((rng.random(M_) > 0.3), jnp.float32),
+                  emit_sq=True)
+    plan = fused_linear_plan(M_, K, F, G, glu, True)
+    assert plan.bk > G and plan.bn > 128
+    out, sq = fused_linear_pallas(x, **kw)
+    old, sq_old = fused_linear_pallas(x, **kw, bm=128, bn=128, bk=G)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(old, np.float32))
+    if epilogue == "residual":
+        np.testing.assert_array_equal(np.asarray(sq), np.asarray(sq_old))
+
+
+# ---------------------------------------------------------------------------
+# The tile plan at the benchmark configurations' widths
+# ---------------------------------------------------------------------------
+
+def _primitives(jaxpr) -> set:
+    """Primitive names of a jaxpr and of the jaxprs nested in it (``jnp.pad``
+    traces as a ``jit`` around ``pad``), the Pallas kernel bodies
+    excepted."""
+    out = set()
+    for e in jaxpr.eqns:
+        out.add(e.primitive.name)
+        if e.primitive.name == "pallas_call":
+            continue
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out |= _primitives(inner)
+    return out
+
+
+def _linears(arch: str):
+    """(name, K, F, glu) of the four fused-linear calls of one layer."""
+    cfg = get_config(arch)
+    d, ai, ki, ff = (cfg.d_model, cfg.attn_inner_dim, cfg.kv_inner_dim,
+                     cfg.d_ff)
+    return [("wqkv", d, ai + 2 * ki, False), ("wo", ai, d, False),
+            ("gu", d, ff, True), ("down", ff, d, False)]
+
+
+@pytest.mark.parametrize("M_", [16, 256, 1024, 2048])
+@pytest.mark.parametrize("arch,lin", [(a, i) for a in ("qwen3-8b",
+                                                        "deepseek-coder-33b")
+                                      for i in range(4)])
+def test_plan_tiles_divide_fit_and_never_pad(arch, lin, M_):
+    name, K, F, glu = _linears(arch)[lin]
+    G = 128
+    p = fused_linear_plan(M_, K, F, G, glu, True)
+    assert not p.fallback, (name, p)
+    assert K % p.bk == 0 and p.bk % G == 0 and p.bk % fl.LANE == 0
+    assert F % p.bn == 0 and p.bn % fl.LANE == 0
+    assert M_ % p.bm == 0 and p.bm <= fl.BM_MAX
+    assert p.bk * p.bn <= fl.W_TILE_MAX
+    assert p.vmem_bytes <= fl.VMEM_BUDGET
+    assert p.grid == (M_ // p.bm, F // p.bn, K // p.bk)
+    # one grid step moves a weight tile of at least 1 MiB per half
+    assert p.bk * p.bn >= 1 << 20, (name, p)
+    # the call as traced pads nothing: no weight (nor x) is copied
+    N = 2 * F if glu else F
+    args = (jax.ShapeDtypeStruct((M_, K), jnp.bfloat16),
+            jax.ShapeDtypeStruct((K, N), jnp.int8),
+            jax.ShapeDtypeStruct((K // G, N), jnp.float32),
+            jax.ShapeDtypeStruct((M_,), jnp.float32),
+            jax.ShapeDtypeStruct((K,), jnp.bfloat16))
+
+    def call(x, codes, scale, ms, gamma):
+        return fused_linear_pallas(x, w_codes=codes, scale=scale,
+                                   mean_sq=ms, gamma=gamma, glu=glu,
+                                   act="silu" if glu else None)
+
+    prims = _primitives(jax.make_jaxpr(call)(*args).jaxpr)
+    assert "pad" not in prims and "pallas_call" in prims
+
+
+_FALLBACK = [
+    # M, K, F, G, glu, int4, bn, bk, pads the weight
+    (16, 256, 130, 128, False, True, 130, 256, False),   # whole odd F
+    (16, 256, 130, 128, True, True, 130, 256, False),    # joint [gate|up]
+    (16, 300, 70, 1, False, False, 70, 300, False),      # dense, odd K
+    (16, 4096, 2100, 128, False, True, 128, 2048, True),  # F over BN_MAX
+    (16, 3000, 256, 1, False, False, 256, 512, True),    # K over BK_MAX
+]
+
+
+@pytest.mark.parametrize("M_,K,F,G,glu,int4,bn,bk,pads", _FALLBACK)
+def test_plan_fallback_for_unaligned_axes(M_, K, F, G, glu, int4, bn, bk,
+                                          pads):
+    """An axis with no lane-aligned dividing tile counts as a fallback:
+    taken whole where it fits (no padding), else in today's 128-wide
+    column tiles or 512-row K steps (int4: one group), padded; the other
+    axis keeps its planned tile."""
+    p = fused_linear_plan(M_, K, F, G, glu, int4,
+                          w_bytes=1 if int4 else 4)
+    assert p.fallback and (p.bn, p.bk) == (bn, bk)
+    assert not fused_linear_plan(M_, 4096, 4096, 128, glu, True).fallback
+    N = 2 * F if glu else F
+    w = ((jax.ShapeDtypeStruct((K, N), jnp.int8),
+          jax.ShapeDtypeStruct((K // G, N), jnp.float32)) if int4
+         else (jax.ShapeDtypeStruct((K, N), jnp.float32),))
+
+    def call(x, *w):
+        args = dict(w_codes=w[0], scale=w[1]) if int4 else dict(w=w[0])
+        return fused_linear_pallas(x, **args, glu=glu, act=None)
+
+    prims = _primitives(jax.make_jaxpr(call)(
+        jax.ShapeDtypeStruct((M_, K), jnp.bfloat16), *w).jaxpr)
+    assert ("pad" in prims) == pads
+
+
+def test_engine_records_the_tiles_each_call_shape_traced_with():
+    """The engine's registry states the plan of every fused-linear call
+    shape its jitted steps traced, under the step's name, and how many
+    of them fell back."""
+    from repro.serve.config import EngineConfig, KVConfig, SchedulingConfig
+    from repro.serve.engine import ContinuousBatchingEngine
+
+    cfg = dataclasses.replace(get_config("llama2-7b").smoke(),
+                              use_kernels=True)
+    params = quantize_params(M.init_params(KEY, cfg), group_size=64,
+                             min_size=1 << 10)
+    eng = ContinuousBatchingEngine(cfg, params, config=EngineConfig(
+        kv=KVConfig(kv_mode="paged", page_size=8, num_pages=32),
+        scheduling=SchedulingConfig(max_slots=2, max_len=32,
+                                    decode_steps=2)))
+    rng = np.random.default_rng(0)
+    for n in (9, 13):
+        eng.submit(rng.integers(0, cfg.vocab_size, (n,), dtype=np.int32),
+                   max_new_tokens=3)
+    eng.run()
+    plans = eng._linear_plans
+    phases = {phase for _, phase in plans}
+    assert {"loop_fn", "prefill_paged_fn"} <= phases, phases
+    m = eng.metrics
+    for (call, phase), plan in plans.items():
+        rows, K, N = map(int, call.split("x"))
+        assert plan.grid[0] == -(-rows // plan.bm)
+        assert m.value("fused_linear_grid_steps", call=call,
+                       phase=phase) == plan.steps
+    # decode calls run every slot's row: M = max_slots
+    assert {int(c.split("x")[0]) for c, p in plans if p == "loop_fn"} == {2}
+    assert m.value("fused_linear_plan_fallbacks_total", default=-1) == sum(
+        p.fallback for p in plans.values())
 
 
 def test_fused_linear_leading_dims_and_jnp_dispatch():

@@ -112,6 +112,49 @@ def test_fused_linear_int4_down_compiles(one_chip, m):
              ((m, D_MODEL), jnp.bfloat16), ((m,), jnp.float32))
 
 
+def _bench_linears():
+    """(arch, name, K, N, kind) of the benchmark configurations' four
+    fused-linear calls per layer, as the model makes them."""
+    from repro.configs import get_config
+    out = []
+    for arch in ("qwen3-8b", "deepseek-coder-33b"):
+        cfg = get_config(arch)
+        d, ai, ki, ff = (cfg.d_model, cfg.attn_inner_dim, cfg.kv_inner_dim,
+                         cfg.d_ff)
+        out += [(arch, "wqkv", d, ai + 2 * ki, "norm"),
+                (arch, "wo", ai, d, "res"),
+                (arch, "gu", d, 2 * ff, "glu"),
+                (arch, "down", ff, d, "res")]
+    return out
+
+
+@pytest.mark.parametrize("m", [16, 2048])
+@pytest.mark.parametrize("lin", range(8))
+def test_fused_linear_planned_tiles_compile(one_chip, lin, m):
+    """The tiles ``fused_linear_plan`` picks at the benchmark widths (decode
+    rows and 512-row prefill blocks) fit the chip's scoped VMEM."""
+    _, _, K, N, kind = _bench_linears()[lin]
+    glu = kind == "glu"
+    shapes = [((m, K), jnp.bfloat16), ((K, N), jnp.int8),
+              ((K // GROUP, N), jnp.float32)]
+    if kind == "res":
+        shapes += [((m, N), jnp.bfloat16), ((m,), jnp.float32)]
+
+        def fn(x, codes, scale, res, gm):
+            return fused_linear_pallas(x, w_codes=codes, scale=scale,
+                                       residual=res, gate_mul=gm,
+                                       emit_sq=True)
+    else:
+        shapes += [((m,), jnp.float32), ((K,), jnp.bfloat16)]
+
+        def fn(x, codes, scale, ms, gamma):
+            return fused_linear_pallas(x, w_codes=codes, scale=scale,
+                                       mean_sq=ms, gamma=gamma, glu=glu,
+                                       act="silu" if glu else None)[0]
+
+    _compile(fn, one_chip, *shapes)
+
+
 def test_int4_matmul_compiles(one_chip):
     """The unfused int4 path: the 32000-wide lm_head at decode M."""
     def fn(x, codes, scale):
