@@ -29,7 +29,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from chipbench import loadgen, spec, weights
+from chipbench import loadgen, spec
 
 PAGE_SIZE = 512
 DECODE_STEPS = 8
@@ -37,7 +37,7 @@ DECODE_STEPS = 8
 
 def build(cell: spec.Cell, seed: int, tracer=None):
     """(cfg, engine) for the cell, with weights drawn on the device from
-    ``seed`` (``weights.py``)."""
+    ``seed`` by the configuration's family (``families/``)."""
     import jax
 
     from repro.serve.config import (EngineConfig, KVConfig, ObsConfig,
@@ -45,10 +45,11 @@ def build(cell: spec.Cell, seed: int, tracer=None):
     from repro.serve.engine import ContinuousBatchingEngine
 
     eng_conf = cell.config["engine"]
-    cfg = dataclasses.replace(spec.model_config(cell.config),
+    fam = cell.family
+    cfg = dataclasses.replace(spec.model_config(cell.config, fam),
                               use_kernels=eng_conf.get("use_kernels", True))
-    params = weights.program_params(spec.weights_key(seed),
-                                    weights.dims_of(cell.config))
+    params = fam.program_params(spec.weights_key(seed),
+                                fam.dims_of(cell.config))
     jax.block_until_ready(params)
     eng = ContinuousBatchingEngine(cfg, params, config=EngineConfig(
         kv=KVConfig(kv_mode="paged",

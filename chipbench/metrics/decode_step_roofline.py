@@ -1,8 +1,8 @@
 """Least time of the window's decode steps at the chip's peaks (the
 weights of the blocks whose routers lean to keeping and every resident's
 stored KV entries read once per step, operations at the int8 peak) over
-the device time of the decode-epoch programs, in %."""
-from chipbench import counts
+the device time of the decode-epoch programs, in %.  The least time is
+the configuration's family's."""
 from chipbench.trace import seconds_of
 
 # program (XLA module) name of the engine's fused paged decode epoch
@@ -20,7 +20,7 @@ def read(ctx):
     least = 0.0
     for e in ctx.epochs:
         mid = e.residents * (e.n - 1) / 2        # steps into the epoch
-        least += e.n * counts.decode_step_least_s(
+        least += e.n * ctx.family.decode_step_least_s(
             ctx.dims, ctx.peaks, e.residents, e.entries + mid * per_token,
             e.ctx_sum + mid, ak, mk, ctx.lean)
     return 100.0 * least / dev

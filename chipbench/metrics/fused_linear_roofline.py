@@ -3,8 +3,8 @@ the kernel's device time, in %.  Per call: 2*M*K*N operations at the
 int8 peak; int4 codes at half a byte, their scales, the activations in
 and out (and the residual) at HBM bandwidth.  Only blocks whose router
 leans to keeping count, and M is the rows they keep: of the residents
-of each decode step, and of the real prompt tokens of each prefill."""
-from chipbench import counts
+of each decode step, and of the real prompt tokens of each prefill.  The
+calls and their counts are the configuration's family's."""
 from chipbench.trace import TPU_KERNEL
 
 LABEL = "fused_linear"
@@ -28,12 +28,13 @@ def read(ctx):
         return None
     calls = [(e.n, e.residents) for e in ctx.epochs]
     calls += [(1, tokens) for tokens, _ in ctx.prefills]
-    shares = counts.row_share(ctx.dims, ctx.lean, ctx.keep)
+    fam = ctx.family
+    kept = fam.kept_linears(ctx.dims, ctx.lean)
+    shares = fam.row_share(ctx.dims, ctx.lean, ctx.keep)
     least = 0.0
     for times, m in calls:
-        for i, (lin, layers) in enumerate(
-                counts.kept_linears(ctx.dims, ctx.lean)):
-            ops, byts = counts.linear_call(lin, m * shares[i // 2])
+        for (lin, layers), share in zip(kept, shares):
+            ops, byts = fam.linear_call(lin, m * share)
             least += times * layers * max(
                 ops / ctx.peaks["int8_ops"],
                 byts / ctx.peaks["hbm_bytes_per_s"])

@@ -3,10 +3,10 @@ over the kernel's device time, in %.  A call is one layer of one decode
 step, and counts for the share of its queries whose gate is open (the
 share of attention gates the reference opens on this run's sample):
 the entries valid at that layer of each attending resident read once, q
-and the output, and the QK and PV dots at the bf16 peak."""
+and the output, and the QK and PV dots at the bf16 peak, as the
+configuration's family counts them."""
 import re
 
-from chipbench import counts
 from chipbench.trace import TPU_KERNEL
 
 LABEL = "paged_attention"
@@ -29,7 +29,7 @@ def read(ctx):
     calls = ctx.keep[0] * ctx.dims.layers       # per step, at full share
     least = 0.0
     for e in ctx.epochs:
-        ops, byts = counts.paged_attention_call(
+        ops, byts = ctx.family.paged_attention_call(
             ctx.dims, e.ctx_sum + e.residents * (e.n - 1) / 2, e.residents,
             1.0)
         least += e.n * calls * max(ops / ctx.peaks["bf16_flops"],

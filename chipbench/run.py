@@ -6,17 +6,21 @@
 
 A cell (an entry of ``workloads`` in BENCHMARK.json) names a
 configuration file and a traffic mix file; this harness finds both by
-name.  In one process it: refuses to start unless JAX's first device is
-a TPU and there are as many as the cell asks for; builds the model
-(random int4 weights drawn on the device from ``--seed``) and the
-engine; serves warm-up requests that reach every program shape the
-window can use, then each client's first request, with a budget that
-staggers the slots as a steady closed loop would (``drive.py``); once
-every client's first request has its first token, measures
-``--seconds`` of closed-loop traffic; frees the program and checks a
-sample of the served tokens against the plain reference (``check.py``).  With ``--trace 1`` the window runs under the profiler
-and the engine's span tracer, and the per-layer metrics (one reader per
-metric under ``chipbench/metrics/``) come from that trace.
+name.  The configuration file's ``family`` names the architecture
+family (``chipbench/families/<family>.py``), which picks the served
+weights, the plain reference and the counts the readers use.  In one
+process it: refuses to start unless JAX's first device is a TPU and
+there are as many as the cell asks for; builds the model (random int4
+weights drawn on the device from ``--seed``) and the engine; serves
+warm-up requests that reach every program shape the window can use,
+then each client's first request, with a budget that staggers the slots
+as a steady closed loop would (``drive.py``); once every client's first
+request has its first token, measures ``--seconds`` of closed-loop
+traffic; frees the program and checks a sample of the served tokens
+against the plain reference (``check.py``).  With ``--trace 1`` the
+window runs under the profiler and the engine's span tracer, and the
+per-layer metrics (one reader per metric under ``chipbench/metrics/``)
+come from that trace.
 
 Facts go to earlier lines of standard output; the numbers compared go
 to the last lines of standard error; the last line of standard output
@@ -26,8 +30,8 @@ with ``--trace 1``), ``device``, with ``--trace 1`` ``breakdown``, and
 last ``compared``.
 
 ``--control 1`` puts the control in the program's place for the
-comparison: the reference one precision step below the configuration
-(``reference.py``), read at the same prompts and served tokens.  Its
+comparison: the family's reference one precision step below the
+configuration, read at the same prompts and served tokens.  Its
 ``correct`` has to come out false.  The benchmark's runs never pass it.
 """
 from time import perf_counter
@@ -36,7 +40,6 @@ T_START = perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -51,8 +54,7 @@ if str(ROOT) not in sys.path:
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(1, str(ROOT / "src"))
 
-from chipbench import (check, drive, loadgen, peaks, reference,  # noqa: E402
-                       spec, weights)
+from chipbench import check, drive, loadgen, peaks, spec  # noqa: E402
 from chipbench import trace as trace_mod  # noqa: E402
 
 TRACE_COUNTERS = ("kv_entries_dense_measured_total",
@@ -111,11 +113,7 @@ def load_metric(root: pathlib.Path, name: str):
     path = root / "chipbench" / "metrics" / f"{name}.py"
     if not path.exists():
         return None
-    sp = importlib.util.spec_from_file_location(f"chipbench_metric_{name}",
-                                                path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod
+    return spec.load_module(path, f"chipbench_metric_{name}")
 
 
 def cell_metrics(bench: dict, cell: str, kind: str) -> list:
@@ -223,6 +221,8 @@ def main(argv=None, require_chip: bool = True,
 
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cell = spec.load_cell(root, args.workload)
+    family = cell.family
+    dims = family.dims_of(cell.config)
 
     import jax
     import numpy as np
@@ -313,14 +313,15 @@ def main(argv=None, require_chip: bool = True,
         r.handle = None     # a handle holds the engine
     gc.collect()
     t = perf_counter()
-    keep = None
+    keep, facts = None, {}
     limits = cell.config["correct_limit"]
     compared = check.compared(None, limits)
     if picked:
         seqs, rows = check.sequences(picked)
         modes = (False, True) if args.control else (False,)
-        out = reference.run(cell.config, spec.weights_key(args.seed), seqs,
-                            rows, lowp_modes=modes)
+        out, facts = family.reference_run(
+            cell.config, spec.weights_key(args.seed), seqs, rows,
+            lowp_modes=modes)
         res = out[False]
         g = check.served_gaps(res, picked)
         keep = (res.attn_keep, res.mlp_keep)
@@ -335,7 +336,7 @@ def main(argv=None, require_chip: bool = True,
         log(f"KV entries stored per request: engine {stored}, reference "
             f"{ref_stored}; attention gates turned by rounding, at least "
             f"{sum(abs(a - b) for a, b in zip(stored, ref_stored))} of "
-            f"{sum(len(s) for s in seqs) * (reference.dims_of(cell.config).layers - 1)}")
+            f"{sum(len(s) for s in seqs) * (dims.layers - 1)}")
         if args.control:
             g = check.control_gaps(res, out[True])
             log(f"control in the program's place: gap max "
@@ -357,12 +358,12 @@ def main(argv=None, require_chip: bool = True,
                 out["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
     else:
         ctx = types.SimpleNamespace(
-            dims=reference.dims_of(cell.config), peaks=pk,
+            family=family, dims=dims, facts=facts, peaks=pk,
             slots=mix["clients"], trace=None, epochs=[], prefills=[],
             snaps=snaps, queue_waits=[], counters=counters, reqs=reqs,
             t_open=t_open, t_close=t_close, keep=keep,
-            lean=tuple(np.asarray(m) for m in weights.keep_masks(
-                spec.weights_key(args.seed), cfg.num_layers)))
+            lean=tuple(np.asarray(m) for m in family.keep_masks(
+                spec.weights_key(args.seed), dims.layers)))
         traced_window(ctx, out, root, bench, cell.name, trace_dir,
                       args.keep_trace, tracer, bucket_for)
     out["compared"] = compared

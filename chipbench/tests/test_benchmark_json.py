@@ -78,7 +78,7 @@ def test_every_cell_reports_setup_another_e2e_and_a_per_layer_metric():
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
 def test_cell_files_resolve(w):
     cell = spec.load_cell(ROOT, w["name"])
-    cfg = spec.model_config(cell.config)
+    cfg = spec.model_config(cell.config, cell.family)
     conf = {c["name"]: c for c in BENCH["configs"]}[w["config"]]
     assert cell.config["name"] == conf["name"]
     assert cell.config["source"] == conf["source"]

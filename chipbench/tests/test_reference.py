@@ -12,6 +12,7 @@ import pytest
 from chipbench import check, reference, spec, weights
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
+DENSE = spec.load_family(CONFIGS.parents[1], "dense_gqa")
 
 
 def smoke_conf(name):
@@ -35,7 +36,7 @@ def test_reference_matches_program_logits(name):
     from repro.models import model
 
     conf = smoke_conf(name)
-    cfg = spec.model_config(conf)
+    cfg = spec.model_config(conf, DENSE)
     key = spec.weights_key(2**31 + 3)
     params = weights.program_params(key, weights.dims_of(conf))
     toks = np.random.default_rng(1).integers(
@@ -56,7 +57,7 @@ def test_reference_weights_are_the_programs(name):
     from repro.quant.int4 import dequantize
 
     conf = smoke_conf(name)
-    cfg = spec.model_config(conf)
+    cfg = spec.model_config(conf, DENSE)
     key = spec.weights_key(7)
     params = model.init_params(key, cfg, quantize=True)
     dm = reference.dims_of(conf)
@@ -90,7 +91,7 @@ def test_program_params_equal_the_programs_own_init(name):
     from repro.models import model
 
     conf = smoke_conf(name)
-    cfg = spec.model_config(conf)
+    cfg = spec.model_config(conf, DENSE)
     key = spec.weights_key(2**31 + 11)
     mine = weights.program_params(key, weights.dims_of(conf))
     theirs = model.init_params(key, cfg, quantize=True)
@@ -152,7 +153,7 @@ def test_program_params_layout_at_full_width():
     from repro.models import model
 
     conf = json.loads((CONFIGS / "qwen3-8b.json").read_text())
-    cfg = spec.model_config(conf)
+    cfg = spec.model_config(conf, DENSE)
     key = jax.random.PRNGKey(0)
     mine = jax.eval_shape(lambda k: weights.program_params(
         k, weights.dims_of(conf)), key)

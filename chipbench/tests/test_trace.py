@@ -155,9 +155,10 @@ def test_every_reader_on_a_traced_window():
 
     import numpy as np
 
-    from chipbench import peaks
+    from chipbench import peaks, run, spec
     from chipbench.reference import Dims
 
+    family = spec.load_family(run.ROOT, "dense_gqa")
     dims = Dims(16, 7168, 56, 8, 128, 19200, 32256, 1e-6, 1e5, False)
     lean = (np.array([True] * 12 + [False] * 4),
             np.array([True] * 12 + [False] * 4))
@@ -172,7 +173,8 @@ def test_every_reader_on_a_traced_window():
 
     def ctx(lean_):
         return types.SimpleNamespace(
-            dims=dims, peaks=peaks.peaks("TPU v5 lite"), slots=16,
+            family=family, dims=dims, facts={},
+            peaks=peaks.peaks("TPU v5 lite"), slots=16,
             trace=red, epochs=epochs, prefills=[(600, 1024)] * 20,
             snaps=snaps, queue_waits=[1.0, 2.0, 3.0],
             counters={"kv_entries_dense_measured_total": 1600.0,

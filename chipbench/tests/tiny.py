@@ -1,6 +1,7 @@
 """A checkout-shaped directory holding one tiny cell, for CPU runs of
 the harness: BENCHMARK.json with the repository's metrics, the tiny
-configuration and traffic mix of ``data/``, and the metric readers."""
+configuration and traffic mix of ``data/``, the architecture families and
+the metric readers."""
 import contextlib
 import io
 import json
@@ -18,9 +19,11 @@ def make_root(tmp: pathlib.Path, mix: str = "tiny-chat",
     (tmp / "chipbench" / "traffic").mkdir(parents=True, exist_ok=True)
     shutil.copy(DATA / "tiny.json", tmp / "chipbench/configs/tiny.json")
     shutil.copy(DATA / f"{mix}.json", tmp / f"chipbench/traffic/{mix}.json")
-    if not (tmp / "chipbench" / "metrics").exists():
-        shutil.copytree(REPO / "chipbench" / "metrics",
-                        tmp / "chipbench" / "metrics")
+    for part in ("metrics", "families"):
+        if not (tmp / "chipbench" / part).exists():
+            shutil.copytree(REPO / "chipbench" / part,
+                            tmp / "chipbench" / part,
+                            ignore=shutil.ignore_patterns("__pycache__"))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     bench["configs"] = [{"name": "tiny", "source": "test",
                          "file": "chipbench/configs/tiny.json",
